@@ -29,8 +29,10 @@ hierarchy (DESIGN §2):
 like the measured backend (normalized delta, paper §III-B).
 
 Hardware constants (TPU v5e, per core): 197 TFLOP/s bf16, 819 GB/s HBM,
-~128 MiB VMEM (half budgeted for double buffering), ~4x HBM bandwidth
-VMEM->VREG, (8, 128) VREGs with a ~32 KiB accumulator/register budget.
+128 MiB VMEM of which the kernel is granted ``tiling.VMEM_LIMIT_BYTES`` (the
+resident tile is budgeted as the kernel holds it: double-buffered blocks
+plus the f32 accumulator), ~4x HBM bandwidth VMEM->VREG, (8, 128) VREGs
+with a ~32 KiB accumulator/register budget.
 """
 from __future__ import annotations
 
@@ -39,15 +41,13 @@ from typing import Dict, List, Tuple
 
 from .loop_ir import Contraction, LoopLevel, LoopNest
 from .measure import PoolHostBackend
+from .tiling import LANES, SUBLANES, VMEM_LIMIT_BYTES, tile_vmem_bytes
 
 PEAK_FLOPS = 197e12  # bf16 per chip
 HBM_BW = 819e9  # bytes/s
 VMEM_BW = 4 * HBM_BW  # VMEM -> VREG sustained
-VMEM_BYTES = 128 * 1024 * 1024
-VMEM_BUDGET = VMEM_BYTES // 2  # double buffering reserve
+VMEM_BUDGET = VMEM_LIMIT_BYTES  # what the compiled kernel may hold
 REG_BUDGET = 32 * 1024  # register-tile budget (VREG file slice)
-LANES = 128
-SUBLANES = 8
 MXU_DEPTH = 8
 GRID_STEP_OVERHEAD_S = 1e-7  # DMA issue + sequencer per VMEM-grid step
 LOOP_TRIP_OVERHEAD_S = 2e-9  # scalar-core loop management per trip
@@ -64,19 +64,25 @@ def _block_extents(
     return ext
 
 
-def _tile_bytes(c: Contraction, ext: Dict[str, int], dtype_bytes: int) -> int:
-    total = 0
-    for t in c.inputs():
-        vol = 1
-        for it in t.iterators:
-            vol *= ext[it]
-        total += vol * dtype_bytes
-    # accumulator tile held in f32
+def _volume(iterators, ext: Dict[str, int]) -> int:
     vol = 1
-    for it in c.out.iterators:
+    for it in iterators:
         vol *= ext[it]
-    total += vol * 4
-    return total
+    return vol
+
+
+def _tile_bytes(c: Contraction, ext: Dict[str, int], dtype_bytes: int) -> int:
+    """Register tile: operand tiles plus the f32 accumulator tile."""
+    return (sum(_volume(t.iterators, ext) for t in c.inputs()) * dtype_bytes
+            + _volume(c.out.iterators, ext) * 4)
+
+
+def _vmem_tile_bytes(c: Contraction, ext: Dict[str, int],
+                     dtype_bytes: int) -> int:
+    """VMEM block as the Pallas kernel holds it (``tiling.tile_vmem_bytes``)."""
+    return tile_vmem_bytes(sum(_volume(t.iterators, ext) for t in c.inputs()),
+                           _volume(c.out.iterators, ext), dtype_bytes,
+                           dtype_bytes)
 
 
 def _grid_trips(levels: List[LoopLevel], b: int, sizes: Dict[str, int]) -> List[int]:
@@ -130,18 +136,20 @@ class TPUAnalyticalBackend(PoolHostBackend):
             return np.array([m.gflops for m in ms], dtype=np.float64)
         return super().evaluate_batch(nests)
 
-    def _boundary(self, nest: LoopNest, budget: int, lo: int = 0) -> int:
-        """Smallest b >= lo whose suffix tile footprint fits ``budget``."""
+    def _boundary(self, nest: LoopNest, budget: int, lo: int = 0,
+                  footprint=_tile_bytes) -> int:
+        """Smallest b >= lo whose suffix tile ``footprint`` fits ``budget``."""
         levels = nest.compute_loops
         sizes = nest.contraction.iter_sizes
         for b in range(lo, len(levels) + 1):
             ext = _block_extents(levels, b, sizes)
-            if _tile_bytes(nest.contraction, ext, self.dtype_bytes) <= budget:
+            if footprint(nest.contraction, ext, self.dtype_bytes) <= budget:
                 return b
         return len(levels)
 
     def residency_boundary(self, nest: LoopNest) -> int:
-        return self._boundary(nest, self.vmem_budget)
+        return self._boundary(nest, self.vmem_budget,
+                              footprint=_vmem_tile_bytes)
 
     # ------------------------------------------------------------------
 
@@ -183,7 +191,7 @@ class TPUAnalyticalBackend(PoolHostBackend):
         c = nest.contraction
         sizes = c.iter_sizes
         levels = nest.compute_loops
-        b_vmem = self._boundary(nest, self.vmem_budget)
+        b_vmem = self.residency_boundary(nest)
         b_reg = self._boundary(nest, self.reg_budget, lo=b_vmem)
         ext_vmem = _block_extents(levels, b_vmem, sizes)
         ext_reg = _block_extents(levels, b_reg, sizes)

@@ -20,8 +20,8 @@ lowers a :class:`LoopNest` to a *single jitted callable* instead:
 3. Nests whose contraction matches a registered kernel shape route through
    the real Pallas kernel instead (``kernels/matmul.py``, block shape and
    grid order lowered from the schedule via
-   :func:`~repro.core.registry.schedule_to_blockspec`; interpret mode on
-   CPU).  See :func:`register_kernel_route`.
+   :func:`~repro.core.registry.schedule_to_blockspec`; Mosaic-compiled on
+   a TPU, interpret mode elsewhere).  See :func:`register_kernel_route`.
 
 Executables are cached by ``structure_key`` in :class:`CompiledKernelCache`
 (LRU — the same eviction discipline as :class:`ScheduleCache`), so
@@ -63,6 +63,7 @@ from .kernel_store import PersistentKernelStore, open_store
 from .loop_ir import Contraction, LoopNest
 from .measure import MeasuredBackend, MeasurementPolicy
 from .schedule_cache import LRUCache
+from ..runtime.device import on_tpu, resolve_interpret
 
 #: environment fallback for the persistent kernel cache dir, so entry points
 #: that never grew a ``cache_dir`` flag still share the fleet cache
@@ -224,17 +225,6 @@ def match_kernel_route(c: Contraction) -> Optional[str]:
     return None
 
 
-def _is_matmul(c: Contraction) -> bool:
-    return (c.rhs is not None
-            and len(c.iter_sizes) == 3
-            and len(c.out.iterators) == 2
-            and len(c.lhs.iterators) == 2
-            and len(c.rhs.iterators) == 2
-            and c.lhs.iterators[0] == c.out.iterators[0]
-            and c.rhs.iterators[1] == c.out.iterators[1]
-            and c.lhs.iterators[1] == c.rhs.iterators[0])
-
-
 def _lower_matmul(nest: LoopNest, interpret: bool) -> Callable:
     """Schedule -> Pallas tiled matmul: the VMEM-resident suffix becomes the
     BlockSpec block shape and the outer levels the grid order (exactly how
@@ -244,9 +234,7 @@ def _lower_matmul(nest: LoopNest, interpret: bool) -> Callable:
     from ..kernels.matmul import matmul
     from .registry import schedule_to_blockspec
 
-    c = nest.contraction
-    m_it, n_it = c.out.iterators
-    k_it = c.lhs.iterators[1]
+    m_it, k_it, n_it = nest.contraction.matmul_iters()
     block, grid_order = schedule_to_blockspec(nest)
     order = "nm" if grid_order.index(n_it) < grid_order.index(m_it) else "mn"
 
@@ -258,7 +246,8 @@ def _lower_matmul(nest: LoopNest, interpret: bool) -> Callable:
     return fn
 
 
-register_kernel_route("matmul", _is_matmul, _lower_matmul)
+register_kernel_route("matmul", lambda c: c.matmul_iters() is not None,
+                      _lower_matmul)
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +288,7 @@ def execute_jax(
     arrays: Dict[str, np.ndarray],
     vec_cap: int = VEC_CAP_DEFAULT,
     route: Optional[str] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> np.ndarray:
     """Execute the schedule through a freshly-built jitted callable; returns
     the output tensor as NumPy.  ``route`` forces a registered kernel route
@@ -375,6 +364,12 @@ class JaxJitBackend(MeasuredBackend):
             raise ValueError(f"pallas must be auto|on|off, got {pallas!r}")
         if prepare not in ("thread", "sync", "off"):
             raise ValueError(f"prepare must be thread|sync|off, got {prepare!r}")
+        if measure == "pool" and on_tpu():
+            raise ValueError(
+                "measure='pool' starts worker processes that each need the "
+                "TPU, but a chip belongs to one process (this one, which "
+                "already holds it); measure in-process with "
+                "measure='inproc'")
         super().__init__(policy=policy, repeats=repeats, measure=measure,
                          pool_workers=pool_workers, isolated=isolated,
                          pool_timeout_s=pool_timeout_s)
@@ -383,7 +378,7 @@ class JaxJitBackend(MeasuredBackend):
         self.pallas = pallas
         self.prepare = prepare
         self.can_prepare = prepare != "off"
-        self.interpret = jax.default_backend() != "tpu"
+        self.interpret = resolve_interpret(None)
         self.kernels = (kernel_cache if kernel_cache is not None
                         else CompiledKernelCache())
         # warm-state bookkeeping must never outlive the executable it
@@ -403,6 +398,7 @@ class JaxJitBackend(MeasuredBackend):
         self.persist_loads = 0    # executables deserialized, not traced
         self.persist_load_s = 0.0
         self.export_errors = 0    # unexportable builds (kept in-proc only)
+        self.last_export_error: Optional[str] = None
         self.deser_errors = 0     # artifacts that failed to deserialize
         self.prepare_errors = 0   # background compile-ahead failures
         self.prepared = 0         # keys handed to the compile-ahead path
@@ -486,8 +482,9 @@ class JaxJitBackend(MeasuredBackend):
             # first warmup run) and ``compile_s`` means the same thing in
             # every mode
             fn = exp.call
-        except Exception:  # noqa: BLE001 — export is best-effort
+        except Exception as e:  # noqa: BLE001 — export is best-effort
             self.export_errors += 1
+            self.last_export_error = f"{type(e).__name__}: {e}"
             data = None
         jitted = jax.jit(fn)
         elapsed = time.perf_counter() - t0
@@ -761,6 +758,7 @@ class JaxJitBackend(MeasuredBackend):
             "persist_loads": self.persist_loads,
             "persist_load_s": round(self.persist_load_s, 4),
             "export_errors": self.export_errors,
+            "last_export_error": self.last_export_error,
             "deser_errors": self.deser_errors,
             "prepared": self.prepared,
             "prepare_errors": self.prepare_errors,

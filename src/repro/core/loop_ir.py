@@ -88,6 +88,20 @@ class Contraction:
     def tensors(self) -> Tuple[TensorSpec, ...]:
         return self.inputs() + (self.out,)
 
+    def matmul_iters(self) -> Optional[Tuple[str, str, str]]:
+        """``(m, k, n)`` iterator names if this is ``out[m,n] = lhs[m,k] @
+        rhs[k,n]`` (the tiled matmul kernel's shape), else None."""
+        if (self.rhs is None or len(self.iter_sizes) != 3
+                or len(self.lhs.iterators) != 2
+                or len(self.rhs.iterators) != 2
+                or len(self.out.iterators) != 2):
+            return None
+        m, n = self.out.iterators
+        k = self.lhs.iterators[1]
+        if self.lhs.iterators != (m, k) or self.rhs.iterators != (k, n):
+            return None
+        return m, k, n
+
     def flops(self) -> int:
         """2 * prod(iter extents) for binary contraction, prod for unary."""
         vol = 1

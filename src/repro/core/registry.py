@@ -43,29 +43,32 @@ _HARDWARE: Optional[str] = None
 def current_hardware() -> str:
     """Stable host descriptor for registry keys (memoized per process).
 
-    On a real accelerator this is the device kind (``TPU v5e`` etc.); on
-    CPU hosts it falls back to the platform triple — coarse, but enough to
-    keep one fleet's tables from silently overriding another's.
+    On a real accelerator this is the device kind (``TPU v5 lite`` etc.);
+    on CPU hosts the platform triple — coarse, but enough to keep one
+    fleet's tables from silently overriding another's.  A failing device
+    query raises: stamping a CPU descriptor on a chip host would write
+    records the chip never looks up.
     """
     global _HARDWARE
     if _HARDWARE is None:
-        kind = None
-        try:  # pragma: no cover - device kind depends on the host
-            import jax
+        import jax
 
-            dev = jax.devices()[0]
-            if dev.platform != "cpu":
-                kind = dev.device_kind
-        except Exception:  # noqa: BLE001 — jax absent/uninitializable
-            kind = None
-        if kind is None:
+        dev = jax.devices()[0]
+        if dev.platform == "cpu":
             import platform
 
             kind = f"cpu-{platform.machine() or 'unknown'}"
+        else:
+            kind = dev.device_kind
         # raw descriptor: record keys escape reserved characters themselves,
         # so a device kind containing ``|`` survives round-trips verbatim
         _HARDWARE = str(kind)
     return _HARDWARE
+
+
+#: operand width every lowered block must fit: the measured kernel route
+#: times f32 operands, and the registry serves that same block to bf16 ones
+BLOCK_OPERAND_BYTES = 4
 
 
 def schedule_to_blockspec(nest: LoopNest, vmem_boundary: Optional[int] = None):
@@ -73,19 +76,31 @@ def schedule_to_blockspec(nest: LoopNest, vmem_boundary: Optional[int] = None):
 
     The resident suffix (innermost levels fitting VMEM — computed by the
     analytical backend unless ``vmem_boundary`` is given) becomes the block;
-    the grid iterates the outer levels in schedule order.  Returns
-    ``(block_sizes: {iter: extent}, grid_order: [iter, ...])``.
+    the grid iterates the outer levels in schedule order.  A matmul's block
+    is then moved to the nearest block the compiled kernel accepts
+    (:func:`~repro.core.tiling.legalize_block`) for
+    :data:`BLOCK_OPERAND_BYTES`-byte operands and an f32 output.
+    Returns ``(block_sizes: {iter: extent}, grid_order: [iter, ...])``.
     """
     from .cost_model import TPUAnalyticalBackend, _block_extents
+    from .tiling import legalize_block
 
+    c = nest.contraction
     levels = nest.compute_loops
-    sizes = nest.contraction.iter_sizes
+    sizes = c.iter_sizes
     b = (
         vmem_boundary
         if vmem_boundary is not None
-        else TPUAnalyticalBackend().residency_boundary(nest)
+        else TPUAnalyticalBackend(dtype_bytes=BLOCK_OPERAND_BYTES)
+        .residency_boundary(nest)
     )
     block = _block_extents(levels, b, sizes)
+    mkn = c.matmul_iters()
+    if mkn is not None:
+        legal = legalize_block(tuple(sizes[it] for it in mkn),
+                               tuple(block[it] for it in mkn),
+                               BLOCK_OPERAND_BYTES, 4)
+        block.update(zip(mkn, legal))
     grid_order = [levels[i].iterator for i in range(b)]
     # iterators with no grid level iterate once (whole dim resident)
     for it in sizes:

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.runtime.device import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -88,8 +90,9 @@ def flash_attention(
     softcap: Optional[float] = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
+    interpret = resolve_interpret(interpret)
     b, s, hq, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
     g = hq // hkv

@@ -15,11 +15,14 @@ tile).  Validated against ``ref.mamba_scan_ref``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime.device import resolve_interpret
 
 
 def _mamba_kernel(dtx_ref, da_ref, b_ref, c_ref, y_ref, hout_ref, h_ref, *,
@@ -73,9 +76,10 @@ def mamba_scan(
     *,
     chunk: int = 32,
     bd: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (y (B, S, C) f32, final_state (B, C, N) f32)."""
+    interpret = resolve_interpret(interpret)
     bsz, s, ch = dtx.shape
     n = b.shape[-1]
     chunk = min(chunk, s)

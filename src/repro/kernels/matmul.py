@@ -8,17 +8,23 @@ accumulator implements LoopNest's register tiling: the output tile stays
 resident across the whole contraction and is written back exactly once.
 
 Validated against ``ref.matmul_ref`` in interpret mode (CPU); on TPU the
-same ``pl.pallas_call`` compiles to a Mosaic kernel.
+same ``pl.pallas_call`` compiles to a Mosaic kernel, granted
+``core.tiling.VMEM_LIMIT_BYTES`` of scoped VMEM.  A compiled call checks its
+block with ``core.tiling.block_error`` first, so an illegal block fails here
+with the block, the shape and the limit named, not inside Mosaic.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.tiling import VMEM_LIMIT_BYTES, block_error
+from repro.runtime.device import resolve_interpret
 
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -53,14 +59,17 @@ def matmul(
     bk: int = 128,
     bn: int = 128,
     grid_order: str = "mn",  # outer-grid traversal: "mn" | "nm"
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     out_dtype=None,
 ) -> jax.Array:
     """C[m, n] = A[m, k] @ B[k, n] with explicit VMEM tiling.
 
     Non-divisible dims are zero-padded (zeros are sum-neutral) and the
     output sliced back — the ``tail`` semantics of the loop IR.
+    ``interpret=None`` compiles with Mosaic on a TPU and interprets
+    elsewhere.
     """
+    interpret = resolve_interpret(interpret)
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)
@@ -73,6 +82,12 @@ def matmul(
     if pk or pn:
         b = jnp.pad(b, ((0, pk), (0, pn)))
     gm, gn, gk = _cdiv(m + pm, bm), _cdiv(n + pn, bn), _cdiv(k + pk, bk)
+    if not interpret:
+        err = block_error((m + pm, k + pk, n + pn), (bm, bk, bn),
+                          jnp.dtype(a.dtype).itemsize,
+                          jnp.dtype(out_dtype).itemsize)
+        if err is not None:
+            raise ValueError(err)
 
     if grid_order == "mn":
         grid = (gm, gn, gk)
@@ -95,6 +110,8 @@ def matmul(
         out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(a, b)
     return out[:m, :n]

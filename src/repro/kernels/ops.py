@@ -5,8 +5,9 @@ BlockSpecs here — `DESIGN §2`).
 ``set_registry(path_or_registry)`` installs a tuned-schedule table (produced
 by ``examples/autotune_matmul.py`` or ``LoopTuner``); every wrapper falls
 back to MXU-aligned defaults when no entry exists.  ``interpret`` defaults
-to True (CPU container); on a real TPU fleet the launch scripts pass
-``interpret=False``.
+to None, which ``runtime.device.resolve_interpret`` turns into a Mosaic
+compile on a TPU and the Pallas interpreter everywhere else; pass a bool
+to force either.
 
 **Tuned serving** (`launch/serve --registry`): :func:`tuned_einsum` is the
 model zoo's consume path.  Inside a :func:`serving` context every
@@ -29,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.registry import ScheduleRegistry, current_hardware
+from repro.runtime.device import on_tpu
 
 from .flash_attention import flash_attention as _flash_attention
 from .mamba_scan import mamba_scan as _mamba_scan
@@ -185,7 +187,7 @@ def _route_pallas(pallas: str) -> Tuple[bool, bool]:
         return True, True
     if pallas == "on":
         return True, False
-    return jax.default_backend() == "tpu", False
+    return on_tpu(), False
 
 
 def tuned_einsum(spec: str, a: jax.Array, b: jax.Array, *,
@@ -252,7 +254,8 @@ def _mm_schedule(m: int, k: int, n: int):
     return block, order
 
 
-def tuned_matmul(a: jax.Array, b: jax.Array, *, interpret: bool = True,
+def tuned_matmul(a: jax.Array, b: jax.Array, *,
+                 interpret: Optional[bool] = None,
                  out_dtype=None) -> jax.Array:
     """Registry-tuned tiled matmul (falls back to 128^3 MXU blocks)."""
     m, k = a.shape
@@ -263,7 +266,7 @@ def tuned_matmul(a: jax.Array, b: jax.Array, *, interpret: bool = True,
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Registry-tuned flash attention (block sizes under kernel id 'fa')."""
     bq, bk = 128, 128
     if _REGISTRY is not None:
@@ -277,7 +280,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
 
 
 def rwkv6_chunk_scan(r, k, v, logw, u, *, chunk: int = 64,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     if _REGISTRY is not None:
         entry = _REGISTRY.get("rwkv6", (r.shape[1], r.shape[2]))
         if entry and "block" in entry:
@@ -287,7 +290,7 @@ def rwkv6_chunk_scan(r, k, v, logw, u, *, chunk: int = 64,
 
 
 def mamba_scan(dtx, da, b, c, *, chunk: int = 32, bd: int = 128,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     if _REGISTRY is not None:
         entry = _REGISTRY.get("mamba", (dtx.shape[1], dtx.shape[2]))
         if entry and "block" in entry:

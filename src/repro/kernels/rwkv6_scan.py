@@ -17,11 +17,14 @@ the log-space data-dependent decay (<= 0).  Validated against
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime.device import resolve_interpret
 
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sout_ref,
@@ -84,9 +87,10 @@ def rwkv6_chunk_scan(
     u: jax.Array,     # (BH, N) per-head bonus
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (y (BH, S, N) f32, final_state (BH, N, N) f32)."""
+    interpret = resolve_interpret(interpret)
     bh, s, n = r.shape
     chunk = min(chunk, s)
     pad = -s % chunk

@@ -44,6 +44,7 @@ from typing import Any, Dict, Optional
 from repro.core.measure import MeasurementPolicy
 from repro.core.measure_service import (MeasureServer, parse_addr,
                                         recv_frame, send_frame)
+from repro.runtime.device import enable_compile_cache
 
 
 def build_server(
@@ -166,6 +167,7 @@ def main(argv=None) -> int:
 
     if args.status:
         return print_status(args.addr)
+    enable_compile_cache()
 
     server = build_server(
         addr=args.addr, backend=args.backend, measure=args.measure,

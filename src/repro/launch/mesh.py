@@ -12,14 +12,9 @@ import jax
 
 
 def _make(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    # jax >= 0.5 takes axis_types (Auto = GSPMD-propagated, our semantics);
-    # jax 0.4.x has neither the kwarg nor AxisType, and Auto is its only
-    # behavior — so omitting the kwarg there is the same mesh.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    # Auto axes: shardings propagate through GSPMD (our semantics)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -33,6 +33,7 @@ from repro.configs import get_config
 from repro.core.registry import ScheduleRegistry
 from repro.models import steps as S
 from repro.models import transformer as T
+from repro.runtime.device import enable_compile_cache
 
 
 class Request:
@@ -184,6 +185,7 @@ def main(argv=None) -> int:
                          "(requires --registry)")
     ap.add_argument("--tune-budget-s", type=float, default=4.0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

@@ -34,6 +34,7 @@ from repro.optim import adamw_init
 from repro.optim.schedules import cosine_with_warmup
 from repro.runtime import sharding as SH
 from repro.runtime.compress import compress_grads, ef_init
+from repro.runtime.device import enable_compile_cache
 from repro.runtime.ft import FailureInjector, FaultTolerantRunner, StragglerWatchdog
 
 
@@ -73,6 +74,7 @@ def main(argv=None) -> int:
                     help="tuned-schedule registry JSON (dense sites consult "
                          "it at trace time; default: plain XLA path)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     registry = None
     if args.registry:

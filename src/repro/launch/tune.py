@@ -3,8 +3,10 @@
 This is the "tune once, off the request path" half of schedule serving
 (AutoTVM's TopHub pattern): lower the model config's serving steps exactly
 as ``launch/serve`` jits them, parse the executed dot contractions out of
-the optimized HLO (``analysis.hlo_parse.harvest_dots`` — occurrence counts
-ride the scan-over-layers trip counts), dedup by structural signature, and
+the lowered, not yet optimized HLO (``analysis.hlo_parse.harvest_dots`` —
+occurrence counts ride the scan-over-layers trip counts; the TPU compiler
+rewrites dots into convolutions, so the optimized HLO would hide them),
+dedup by structural signature, and
 spend the tuning budget proportionally to each contraction's executed-FLOP
 share so the roofline-dominant shapes get tuned hardest.  Best schedules
 land in a :class:`~repro.core.registry.ScheduleRegistry` table that
@@ -12,6 +14,9 @@ land in a :class:`~repro.core.registry.ScheduleRegistry` table that
 
     PYTHONPATH=src python -m repro.launch.tune --arch musicgen-large \
         --registry /tmp/musicgen.json --budget-s 4
+
+The serving shapes (``--batch``/``--prompt-len``/``--max-len``) default to
+``launch/serve``'s, because registry keys are exact shapes.
 
 Tuning is **crash-resumable**: per-contraction results append to a JSONL
 journal (default ``<registry>.journal.jsonl``) the moment each contraction
@@ -37,6 +42,7 @@ from repro.core.loop_ir import matmul_benchmark
 from repro.core.registry import ScheduleRegistry
 from repro.core.rl_common import epsilon_ladder
 from repro.core.tuner import LoopTuner
+from repro.runtime.device import enable_compile_cache
 
 
 class TuneJournal:
@@ -101,18 +107,19 @@ def harvest_model(
     cfg,
     *,
     batch: int = 4,
-    prompt_len: int = 24,
-    max_len: int = 64,
+    prompt_len: int = 32,
+    max_len: int = 128,
     kinds: Sequence[str] = ("decode", "prefill"),
 ) -> List[Dict[str, Any]]:
     """Executed dot contractions of a model's serving steps.
 
-    Lowers + compiles the decode (and prefill) step functions with
-    ShapeDtypeStruct stand-ins — zero allocation, same jit the server
-    builds — and returns :func:`harvest_dots` records aggregated across
-    step kinds, sorted by executed-FLOP share.  ``batch``/``prompt_len``/
-    ``max_len`` must match the serving shapes for the harvested workload
-    keys to be the ones the server looks up.
+    Lowers the decode (and prefill) step functions with ShapeDtypeStruct
+    stand-ins — zero allocation, no compile, same jit the server builds —
+    and returns :func:`harvest_dots` records of the lowered HLO module
+    (the same on every platform) aggregated across step kinds, sorted by
+    executed-FLOP share.  ``batch``/``prompt_len``/``max_len`` must match
+    the serving shapes for the harvested workload keys to be the ones the
+    server looks up.
     """
     import jax
 
@@ -136,7 +143,8 @@ def harvest_model(
             lowered = fn.lower(params, specs["batch"])
         else:
             raise ValueError(f"unknown step kind {kind!r}")
-        for rec in harvest_dots(lowered.compile().as_text()):
+        hlo = lowered.compiler_ir("hlo").get_hlo_module().to_string()
+        for rec in harvest_dots(hlo):
             # fold batch dims into m: a batched GEMM tunes as (b*m, k, n)
             key = (rec["batch"] * rec["m"], rec["k"], rec["n"], rec["dtype"])
             slot = agg.setdefault(key, {"count": 0.0, "flops": 0.0})
@@ -355,8 +363,8 @@ def tune_model(
     max_contractions: int = 12,
     smoke: bool = True,
     batch: int = 4,
-    prompt_len: int = 24,
-    max_len: int = 64,
+    prompt_len: int = 32,
+    max_len: int = 128,
     kinds: Sequence[str] = ("decode", "prefill"),
     kernel_cache: Optional[str] = None,
     farm: Optional[str] = None,
@@ -384,7 +392,8 @@ def tune_model(
                          "tuners")
     if registry is None:
         registry = ScheduleRegistry(registry_path)
-    if tuner is None and fleet <= 1:
+    owns_backend = tuner is None and fleet <= 1
+    if owns_backend:
         # --farm: timings come from a remote measurement farm; ``backend``
         # becomes the local fallback the client degrades to if the farm is
         # unreachable (a tune is never failed by the farm)
@@ -439,7 +448,7 @@ def tune_model(
     tb = tuner.backend if tuner is not None else None
     compile_stats = getattr(tb, "compile_stats", None)
     farm_stats = getattr(tb, "farm_stats", None)
-    return {
+    report = {
         "arch": cfg.name,
         "kinds": list(kinds),
         "shapes": {"batch": batch, "prompt_len": prompt_len,
@@ -466,11 +475,14 @@ def tune_model(
             for r, e in zip(kept, entries)
         ],
     }
+    if owns_backend:
+        tb.close()  # compile-ahead thread, worker pool, farm connection
+    return report
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True)
+    ap.add_argument("--arch", default="musicgen-large")
     ap.add_argument("--registry", required=True, help="registry JSON path")
     ap.add_argument("--full", action="store_true",
                     help="published config (fleet scale); default smoke")
@@ -481,8 +493,8 @@ def main(argv=None) -> int:
     ap.add_argument("--eval-budget", type=int, default=None)
     ap.add_argument("--max-contractions", type=int, default=12)
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=24)
-    ap.add_argument("--max-len", type=int, default=64)
+    ap.add_argument("--prompt-len", type=int, default=32)
+    ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--kernel-cache", default=None,
                     help="persistent compiled-kernel store dir (jax "
                          "backends; default: <registry>.kernels; 'off' "
@@ -503,6 +515,7 @@ def main(argv=None) -> int:
                     help="skip contractions already in the journal (after "
                          "a crash/kill: re-tunes only unfinished work)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # the kernel store lives beside the registry by default: the artifacts
     # and the schedules they serve travel (and get wiped) together

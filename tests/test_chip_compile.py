@@ -1,0 +1,215 @@
+"""The compiled kernel path, checked without a chip.
+
+The topology tests compile the Pallas matmul and musicgen-large's routed
+serving steps for a described TPU v5e (no chip attached): the TPU compiler
+installed here refuses what Mosaic would refuse on the chip — unaligned
+blocks, blocks over the VMEM limit — which interpret mode never sees.  The
+v5e topology is described inside a module fixture, so only the worker that
+runs this file loads the TPU library.  The CPU tests cover the legality
+check, the compile-cache placement and the harvest's platform independence.
+"""
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.actions import CPU_SPLITS, apply_action, build_action_space
+from repro.core.loop_ir import LoopNest, matmul_benchmark
+from repro.core.registry import schedule_to_blockspec
+from repro.core.tiling import (VMEM_LIMIT_BYTES, block_error,
+                               legalize_block)
+from repro.kernels.matmul import matmul
+from repro.runtime import device as D
+
+# musicgen-large's dense dots at batch 4, prompt 32 (launch.tune harvest):
+# decode m = 4, prefill m = 4 * 32
+MUSICGEN_DOTS = {
+    "decode-ffn-up": (4, 2048, 8192),
+    "decode-ffn-down": (4, 8192, 2048),
+    "prefill-ffn-up": (128, 2048, 8192),
+    "prefill-attn-proj": (128, 2048, 2048),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for the described chip cannot be read back from the
+    # persistent cache without the chip: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile_matmul(one_chip, shape, block, dtype, out_dtype):
+    m, k, n = shape
+    bm, bk, bn = block
+    fn = jax.jit(lambda a, b: matmul(a, b, bm=bm, bk=bk, bn=bn,
+                                     interpret=False, out_dtype=out_dtype))
+    return fn.lower(jax.ShapeDtypeStruct((m, k), dtype, sharding=one_chip),
+                    jax.ShapeDtypeStruct((k, n), dtype, sharding=one_chip)
+                    ).compile()
+
+
+@pytest.mark.parametrize("name", sorted(MUSICGEN_DOTS))
+def test_tuned_matmul_compiles_for_v5e(one_chip, name):
+    """The block schedule_to_blockspec emits for the untuned nest (whole
+    arrays, the case that once ran out of VMEM) and for split nests from
+    the measured backend's ladder compiles as served (bf16) and as timed
+    (f32 in, f32 out)."""
+    shape = MUSICGEN_DOTS[name]
+    nests = [LoopNest(matmul_benchmark(*shape))]
+    split = LoopNest(matmul_benchmark(*shape))
+    for a in build_action_space(CPU_SPLITS):
+        if a.name in ("split_64", "down"):
+            apply_action(split, a)  # 64-wide blocks: unaligned to 128 lanes
+    nests.append(split)
+    for nest in nests:
+        block, _ = schedule_to_blockspec(nest)
+        bmkn = (block["m"], block["k"], block["n"])
+        for dtype in (jnp.bfloat16, jnp.float32):
+            compiled = _compile_matmul(one_chip, shape, bmkn, dtype, dtype)
+            assert "tpu_custom_call" in compiled.as_text(), (name, bmkn)
+
+
+def test_routed_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """musicgen-large's full-width decode step, every dense dot routed
+    through the Pallas kernel with a registry block, compiles for v5e."""
+    from repro.configs import ShapeCell, get_config, input_specs
+    from repro.core.registry import ScheduleRegistry
+    from repro.kernels import ops
+    from repro.models import steps as S
+    from repro.models import transformer as T
+
+    cfg = get_config("musicgen-large")
+    reg = ScheduleRegistry()
+    for m, k, n in [(4, 2048, 2048), (4, 2048, 8192), (4, 8192, 2048)]:
+        reg.put("mm", (m, k, n), 1.0, [], LoopNest(matmul_benchmark(m, k, n)),
+                dtype=cfg.dtype)
+    # on this CPU host "auto" keeps the XLA lowering: force the compiled route
+    monkeypatch.setattr(ops, "_route_pallas", lambda pallas: (True, False))
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    specs = input_specs(cfg, ShapeCell("serve", 128, 4, "decode"))
+    ops.reset_serving_stats()
+    compiled = jax.jit(S.make_decode_step(cfg, registry=reg)).lower(
+        params, place(specs["batch"]), place(specs["caches"]),
+        place(specs["cache_len"])).compile()
+    stats = ops.serving_stats(reset=True)
+    assert stats["misses"] == 0 and stats["routed"] == stats["hits"] > 0
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_harvest_is_platform_independent(one_chip):
+    """The harvest reads the lowered HLO: lowering for the TPU gives the
+    same dots as lowering for the CPU (the TPU's optimized HLO turns them
+    into convolutions, where the harvest would find none)."""
+    from repro.analysis.hlo_parse import harvest_dots
+    from repro.configs import ShapeCell, get_config, input_specs
+    from repro.models import steps as S
+    from repro.models import transformer as T
+
+    cfg = get_config("musicgen-large").smoke()
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    specs = input_specs(cfg, ShapeCell("serve", 64, 4, "decode"))
+    args = (params, specs["batch"], specs["caches"], specs["cache_len"])
+
+    def dots(lowered):
+        hlo = lowered.compiler_ir("hlo").get_hlo_module().to_string()
+        return sorted((r["batch"] * r["m"], r["k"], r["n"], r["dtype"],
+                       r["count"]) for r in harvest_dots(hlo))
+
+    step = jax.jit(S.make_decode_step(cfg))
+    on_tpu = step.lower(*jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), args))
+    assert dots(on_tpu) == dots(step.lower(*args)) != []
+
+
+# ---------------------------------------------------------------------------
+# CPU only: the legality check and the compile-cache placement
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("block,why", [
+    ((4, 64, 8192), "bk=64"),     # not a multiple of the 128 lanes
+    ((4, 2048, 200), "bn=200"),   # nor is this, and it is not n either
+    ((4, 2048, 8192), "VMEM"),    # whole f32 arrays: over the VMEM limit
+])
+def test_compiled_matmul_refuses_illegal_block(block, why):
+    a = jnp.zeros((4, 2048), jnp.float32)
+    b = jnp.zeros((2048, 8192), jnp.float32)
+    with pytest.raises(ValueError) as e:
+        matmul(a, b, bm=block[0], bk=block[1], bn=block[2], interpret=False)
+    msg = str(e.value)
+    assert f"illegal block (bm, bk, bn)={block}" in msg
+    assert "(4, 2048, 8192)" in msg or why == "bn=200"
+    assert str(VMEM_LIMIT_BYTES) in msg and why in msg
+
+
+def test_legalized_blocks_pass_the_check():
+    rng = np.random.default_rng(0)
+    for _ in range(300):
+        shape = tuple(int(x) for x in rng.integers(1, 9000, 3))
+        block = tuple(int(rng.integers(1, d + 1)) for d in shape)
+        for in_b, out_b in ((4, 4), (2, 2), (2, 4)):
+            legal = legalize_block(shape, block, in_b, out_b)
+            padded = tuple(-(-d // b) * b for d, b in zip(shape, legal))
+            assert block_error(padded, legal, in_b, out_b) is None
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    monkeypatch.delenv(D.CACHE_ENV, raising=False)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert D.enable_compile_cache() == D.DEFAULT_COMPILE_CACHE
+        assert jax.config.jax_compilation_cache_dir == D.DEFAULT_COMPILE_CACHE
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert D.DEFAULT_COMPILE_CACHE == os.path.join(root, ".jax_compile_cache")
+
+
+_CACHE_CHILD = """
+import jax, jax.numpy as jnp
+from repro.runtime.device import enable_compile_cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+path = enable_compile_cache()
+jax.jit(lambda x: jnp.sin(x) @ x)(jnp.ones((64, 64))).block_until_ready()
+print(path, jax.config.jax_compilation_cache_dir)
+"""
+
+
+def test_compile_cache_env_dir_is_the_only_cache(tmp_path):
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "src"), env.get("PYTHONPATH", "")])
+    out = subprocess.run([sys.executable, "-c", _CACHE_CHILD], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [str(tmp_path), str(tmp_path)]
+    assert os.listdir(tmp_path), "nothing was cached in the env's directory"

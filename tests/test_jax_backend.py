@@ -368,3 +368,15 @@ def test_meta_none_backend_uses_env(tmp_path):
         lambda _: LoopTuneEnv([matmul_benchmark(8, 8, 8)], "tpu", seed=0),
         1, A2CConfig(hidden=(16,), n_envs=2, rollout_len=4))
     assert res.meta["backend"] == "tpu"  # recorded from the env's executor
+
+
+def test_jax_pool_refused_on_tpu(monkeypatch):
+    """One process per chip: pool workers would each need the TPU the
+    parent already holds, so the jax backend refuses the pool there."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="measure='inproc'"):
+        make_backend("jax", measure="pool", pool_workers=2)
+    with pytest.raises(ValueError, match="one process"):
+        make_backend("auto", measure="pool")

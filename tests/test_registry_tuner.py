@@ -75,3 +75,19 @@ def test_policy_checkpoint_tuner(tmp_path):
     tuner = LoopTuner.from_checkpoint(path, backend="tpu")
     e = tuner.tune_matmul(96, 96, 96)
     assert e["gflops"] > 0 and e["tune_time_s"] < 10
+
+
+def test_current_hardware_raises_when_device_query_fails(monkeypatch):
+    """A failed device query must not stamp a CPU descriptor: the chip would
+    never look those records up."""
+    import jax
+
+    from repro.core import registry as R
+
+    def broken():
+        raise RuntimeError("no backend")
+
+    monkeypatch.setattr(R, "_HARDWARE", None)
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="no backend"):
+        R.current_hardware()
