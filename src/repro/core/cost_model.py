@@ -46,7 +46,6 @@ from .tiling import LANES, SUBLANES, VMEM_LIMIT_BYTES, tile_vmem_bytes
 PEAK_FLOPS = 197e12  # bf16 per chip
 HBM_BW = 819e9  # bytes/s
 VMEM_BW = 4 * HBM_BW  # VMEM -> VREG sustained
-VMEM_BUDGET = VMEM_LIMIT_BYTES  # what the compiled kernel may hold
 REG_BUDGET = 32 * 1024  # register-tile budget (VREG file slice)
 MXU_DEPTH = 8
 GRID_STEP_OVERHEAD_S = 1e-7  # DMA issue + sequencer per VMEM-grid step
@@ -114,18 +113,15 @@ class TPUAnalyticalBackend(PoolHostBackend):
 
     name = "tpu"
 
-    def __init__(self, dtype_bytes: int = 2, vmem_budget: int = VMEM_BUDGET,
-                 reg_budget: int = REG_BUDGET,
+    def __init__(self, dtype_bytes: int = 2, reg_budget: int = REG_BUDGET,
                  measure: str = "inproc", pool_workers=None, policy=None,
                  pool_timeout_s=None):
         self._init_pool_host(measure, pool_workers, policy, pool_timeout_s)
         self.dtype_bytes = dtype_bytes
-        self.vmem_budget = vmem_budget
         self.reg_budget = reg_budget
 
     def pool_spec(self):
         return ("tpu", {"dtype_bytes": self.dtype_bytes,
-                        "vmem_budget": self.vmem_budget,
                         "reg_budget": self.reg_budget}, None)
 
     def evaluate_batch(self, nests) -> "np.ndarray":
@@ -148,7 +144,7 @@ class TPUAnalyticalBackend(PoolHostBackend):
         return len(levels)
 
     def residency_boundary(self, nest: LoopNest) -> int:
-        return self._boundary(nest, self.vmem_budget,
+        return self._boundary(nest, VMEM_LIMIT_BYTES,
                               footprint=_vmem_tile_bytes)
 
     # ------------------------------------------------------------------

@@ -63,7 +63,7 @@ from .kernel_store import PersistentKernelStore, open_store
 from .loop_ir import Contraction, LoopNest
 from .measure import MeasuredBackend, MeasurementPolicy
 from .schedule_cache import LRUCache
-from ..runtime.device import on_tpu, resolve_interpret
+from ..runtime.device import on_tpu
 
 #: environment fallback for the persistent kernel cache dir, so entry points
 #: that never grew a ``cache_dir`` flag still share the fleet cache
@@ -378,7 +378,7 @@ class JaxJitBackend(MeasuredBackend):
         self.pallas = pallas
         self.prepare = prepare
         self.can_prepare = prepare != "off"
-        self.interpret = resolve_interpret(None)
+        self.interpret = not on_tpu()
         self.kernels = (kernel_cache if kernel_cache is not None
                         else CompiledKernelCache())
         # warm-state bookkeeping must never outlive the executable it
