@@ -1,0 +1,9 @@
+"""idle_share.tune: the share of the traced tuning window in which no
+operation ran on the device, in %: the tuner's host work (harvest, search,
+kernel compiles) against its timing runs on the chip."""
+
+
+def read(run):
+    if run.trace is None or run.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace.busy_s / run.trace.window_s)
