@@ -64,6 +64,7 @@ from .loop_ir import Contraction, LoopNest
 from .measure import MeasuredBackend, MeasurementPolicy
 from .schedule_cache import LRUCache
 from ..runtime.device import on_tpu
+from ..runtime.spans import count, span, timed
 
 #: environment fallback for the persistent kernel cache dir, so entry points
 #: that never grew a ``cache_dir`` flag still share the fleet cache
@@ -395,6 +396,7 @@ class JaxJitBackend(MeasuredBackend):
         # compile accounting — the "never wait on the compiler twice" ledger
         self.compiles = 0         # actual traces performed by this process
         self.compile_s = 0.0      # seconds spent tracing/exporting
+        self.backend_compile_s = 0.0  # first calls: XLA's lazy compile
         self.persist_loads = 0    # executables deserialized, not traced
         self.persist_load_s = 0.0
         self.export_errors = 0    # unexportable builds (kept in-proc only)
@@ -456,49 +458,63 @@ class JaxJitBackend(MeasuredBackend):
         the serialized artifact ships fleet-wide; unexportable programs
         degrade to plain in-process JIT (counted, never fatal).  XLA's
         backend compile of the staged module stays lazy: it costs the same
-        whether the module was traced here or loaded from the store, lands
-        in the measurement warmup on both paths, and is therefore excluded
-        from the compile accounting symmetrically."""
+        whether the module was traced here or loaded from the store and is
+        left out of ``compile_s`` on both paths; the executable's first call
+        pays it, under the ``looptune.compile.backend`` span
+        (``backend_compile_s``)."""
         import jax
 
         route = key[2]
-        t0 = time.perf_counter()
-        if route is not None:
-            fn = _KERNEL_ROUTES[route][1](nest, self.interpret)
-        else:
-            fn = _build_slab_fn(nest, self.vec_cap)
-        data: Optional[bytes] = None
-        try:
-            from jax import export
+        with timed("looptune.compile.trace") as sp:
+            if route is not None:
+                fn = _KERNEL_ROUTES[route][1](nest, self.interpret)
+            else:
+                fn = _build_slab_fn(nest, self.vec_cap)
+            data: Optional[bytes] = None
+            try:
+                from jax import export
 
-            exp = export.export(jax.jit(fn))(
-                *self._abstract_args(nest.contraction))
-            if self.store is not None:
-                data = exp.serialize()
-            # run through the exported program in-process too — fleet
-            # members time the exact same XLA module they load, and the
-            # storeless path stages through export as well so the expensive
-            # Python trace lands under the compile timer (not inside the
-            # first warmup run) and ``compile_s`` means the same thing in
-            # every mode
-            fn = exp.call
-        except Exception as e:  # noqa: BLE001 — export is best-effort
-            self.export_errors += 1
-            self.last_export_error = f"{type(e).__name__}: {e}"
-            data = None
-        jitted = jax.jit(fn)
-        elapsed = time.perf_counter() - t0
+                exp = export.export(jax.jit(fn))(
+                    *self._abstract_args(nest.contraction))
+                if self.store is not None:
+                    data = exp.serialize()
+                # run through the exported program in-process too — fleet
+                # members time the exact same XLA module they load, and the
+                # storeless path stages through export as well so the
+                # expensive Python trace lands under the compile timer (not
+                # inside the first warmup run) and ``compile_s`` means the
+                # same thing in every mode
+                fn = exp.call
+            except Exception as e:  # noqa: BLE001 — export is best-effort
+                self.export_errors += 1
+                self.last_export_error = f"{type(e).__name__}: {e}"
+                data = None
+            jitted = jax.jit(fn)
         self.compiles += 1
-        self.compile_s += elapsed
+        self.compile_s += sp.seconds
         if self.store is not None:
-            self.store.log_compile(key, elapsed)
+            self.store.log_compile(key, sp.seconds)
         return jitted, data
 
-    def _deserialize(self, data: bytes) -> Callable:
+    def _deserialize(self, key: Tuple, data: bytes) -> Optional[Callable]:
+        """A stored artifact turned back into an executable, or None when
+        it fails to deserialize (the artifact is dropped, so the next
+        builder replaces it)."""
         import jax
         from jax import export
 
-        return jax.jit(export.deserialize(data).call)
+        with timed("looptune.compile.load") as sp:
+            try:
+                fn = jax.jit(export.deserialize(data).call)
+            except Exception:  # noqa: BLE001 — fall back to in-process JIT
+                fn = None
+        if fn is None:
+            self.deser_errors += 1
+            self.store.discard(key)
+            return None
+        self.persist_loads += 1
+        self.persist_load_s += sp.seconds
+        return fn
 
     def _load_from_store(self, key: Tuple) -> Optional[Callable]:
         """A shared artifact turned back into an executable, or None
@@ -509,19 +525,12 @@ class JaxJitBackend(MeasuredBackend):
         data = self.store.load(key)
         if data is None:
             return None
-        t0 = time.perf_counter()
-        try:
-            fn = self._deserialize(data)
-        except Exception:  # noqa: BLE001 — fall back to in-process JIT
-            self.deser_errors += 1
-            self.store.discard(key)
+        fn = self._deserialize(key, data)
+        if fn is None:
             from .kernel_store import _warn_once
 
             _warn_once(self.store.root, "artifact failed to deserialize",
                        "jax/device mismatch or truncated file")
-            return None
-        self.persist_loads += 1
-        self.persist_load_s += time.perf_counter() - t0
         return fn
 
     def _make_executable(self, nest: LoopNest, key: Tuple) -> Callable:
@@ -542,17 +551,12 @@ class JaxJitBackend(MeasuredBackend):
                     self.store.release_build_lock(key)
             return fn
         # a peer is already tracing this key: wait on the shared artifact
-        data = self.store.wait_for(key)
+        with span("looptune.compile.wait"):
+            data = self.store.wait_for(key)
         if data is not None:
-            t0 = time.perf_counter()
-            try:
-                loaded = self._deserialize(data)
-                self.persist_loads += 1
-                self.persist_load_s += time.perf_counter() - t0
+            loaded = self._deserialize(key, data)
+            if loaded is not None:
                 return loaded
-            except Exception:  # noqa: BLE001
-                self.deser_errors += 1
-                self.store.discard(key)
         fn, _ = self._trace(nest, key)  # builder died/timed out: build here
         return fn
 
@@ -570,7 +574,9 @@ class JaxJitBackend(MeasuredBackend):
                     self.kernels.hits += 1
                     return fn
                 if key in self._building:
-                    self._compile_cv.wait()
+                    with span("looptune.compile.wait"):
+                        while key in self._building:
+                            self._compile_cv.wait()
                     continue
                 self.kernels.misses += 1
                 self._building.add(key)
@@ -663,26 +669,49 @@ class JaxJitBackend(MeasuredBackend):
         if self._compile_q is not None:
             self._compile_q.put(None)
             if self._compile_thread is not None:
-                self._compile_thread.join(timeout=5.0)
+                # the builds still queued finish first: a wait on them
+                with span("looptune.compile.wait"):
+                    self._compile_thread.join(timeout=5.0)
             self._compile_q = None
             self._compile_thread = None
         super().close()
 
     def _inputs(self, c: Contraction) -> Tuple:
         def build():
+            import jax
             import jax.numpy as jnp
 
-            arrays = make_inputs(c, self.seed)
-            return tuple(jnp.asarray(arrays[t.name]) for t in c.inputs())
+            with span("looptune.inputs"):
+                arrays = make_inputs(c, self.seed)
+                out = jax.block_until_ready(tuple(
+                    jnp.asarray(arrays[t.name]) for t in c.inputs()))
+            count("looptune.inputs.bytes",
+                  sum(a.nbytes for a in arrays.values()))
+            return out
 
         return self._inputs_cache.get_or_create(c.name, build)
 
+    def _call(self, nest: LoopNest):
+        """The (cached) executable on the backend's operand set.  Its first
+        call in this process pays XLA's lazy backend compile (Mosaic's, for
+        a kernel route), whether the program was traced here or loaded
+        from the store."""
+        fn = self.executable(nest)
+        args = self._inputs(nest.contraction)
+        key = self._compile_key(nest)
+        if key in self._executed:
+            return fn(*args)
+        import jax
+
+        with timed("looptune.compile.backend") as sp:
+            out = jax.block_until_ready(fn(*args))
+        self.backend_compile_s += sp.seconds
+        self._executed.add(key)
+        return out
+
     def execute(self, nest: LoopNest) -> np.ndarray:
         """Run the (cached) executable on the backend's operand set."""
-        out = np.asarray(
-            self.executable(nest)(*self._inputs(nest.contraction)))
-        self._executed.add(self._compile_key(nest))
-        return out
+        return np.asarray(self._call(nest))
 
     # -- executor surface (timing lives in MeasuredBackend) ------------------
 
@@ -690,9 +719,7 @@ class JaxJitBackend(MeasuredBackend):
         """One synchronized run of the compiled program (the untimed policy
         warm-up run pays any compilation — tracing *and* the lazy XLA
         compile a store-loaded program still owes at its first call)."""
-        fn = self.executable(nest)
-        fn(*self._inputs(nest.contraction)).block_until_ready()
-        self._executed.add(self._compile_key(nest))
+        self._call(nest).block_until_ready()
 
     def is_warm(self, nest: LoopNest) -> bool:
         """Warm-up is elidable only once *this structure's* executable has
@@ -755,6 +782,7 @@ class JaxJitBackend(MeasuredBackend):
             "compile_misses": self.compiles,
             "compile_hits": self.kernels.hits + self.persist_loads,
             "compile_s": round(self.compile_s, 4),
+            "backend_compile_s": round(self.backend_compile_s, 4),
             "persist_loads": self.persist_loads,
             "persist_load_s": round(self.persist_load_s, 4),
             "export_errors": self.export_errors,

@@ -59,6 +59,7 @@ import numpy as np
 from .backend import Backend
 from .loop_ir import Contraction, LoopNest
 from .schedule_cache import DEFAULT_CAPACITY, LRUCache
+from ..runtime.spans import count, span
 
 #: bounded per-backend map from structure_key to its latest Measurement.
 #: Must not evict before the ScheduleCache holding the values does
@@ -258,31 +259,38 @@ class MeasurementPolicy:
     ) -> Measurement:
         """Time ``run_once`` under the guardrails; returns a
         :class:`Measurement`.  ``warm=True`` marks an isolated, already-warm
-        execution site (warmups elided when ``warm_elide``)."""
+        execution site (warmups elided when ``warm_elide``).
+
+        One ``looptune.measure`` span covers the warm-ups and the timed
+        runs; what a warm-up's first call pays (compile, operands) opens
+        spans of its own inside it.  No span opens or closes between the
+        two clock reads of a timed run."""
         clock = self.clock if self.clock is not None else time.perf_counter
-        if not (warm and self.warm_elide):
-            for _ in range(self.warmup):
+        with span("looptune.measure"):
+            warmups = 0 if warm and self.warm_elide else self.warmup
+            for _ in range(warmups):
                 run_once()
-        times: List[float] = []
-        target = self.repeats
-        escalations = 0
-        gc_was_on = self.gc_guard and gc.isenabled()
-        if gc_was_on:
-            gc.disable()
-        try:
-            while True:
-                while len(times) < target:
-                    t0 = clock()
-                    run_once()
-                    times.append(clock() - t0)
-                spread = self.window_spread(times)
-                if spread <= self.spread_threshold or target >= self.max_repeats:
-                    break
-                escalations += 1
-                target = min(self.max_repeats, target * self.escalate_factor)
-        finally:
+            times: List[float] = []
+            target = self.repeats
+            escalations = 0
+            gc_was_on = self.gc_guard and gc.isenabled()
             if gc_was_on:
-                gc.enable()
+                gc.disable()
+            try:
+                while True:
+                    while len(times) < target:
+                        t0 = clock()
+                        run_once()
+                        times.append(clock() - t0)
+                    spread = self.window_spread(times)
+                    if spread <= self.spread_threshold or target >= self.max_repeats:
+                        break
+                    escalations += 1
+                    target = min(self.max_repeats, target * self.escalate_factor)
+            finally:
+                if gc_was_on:
+                    gc.enable()
+        count("looptune.measure.runs", warmups + len(times))
         best = min(times)
         return Measurement(
             gflops=flops / max(best, 1e-12) / 1e9,

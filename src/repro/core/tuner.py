@@ -31,6 +31,7 @@ from .schedule_cache import ScheduleCache
 from .search import beam_search, greedy_search
 from .surrogate import SurrogateScorer
 from .vec_env import VecLoopTuneEnv
+from ..runtime.spans import timed
 
 # "warn once": legacy checkpoints without a recorded peak trip this on the
 # first load in a process, not on every tune() call
@@ -245,30 +246,32 @@ class LoopTuner:
     def tune(self, bench: Contraction, kernel: str = "mm", *,
              dtype: str = "float32", budget_s: Optional[float] = None,
              max_evals: Optional[int] = None) -> Dict[str, Any]:
-        """Tune one contraction; returns the registry entry."""
-        t0 = time.perf_counter()
-        budget_s = budget_s if budget_s is not None else self.search_budget_s
-        env = self._env_for(bench)
-        if self.policy == "policy":
-            best_g, actions, nest = greedy_rollout(env, self.act, 0)
-        elif self.policy == "search":
-            scorer = self._scorer_for(env)
-            res = greedy_search(env, 0, lookahead=1, budget_s=budget_s,
-                                max_evals=max_evals, surrogate=scorer)
-            res2 = beam_search(env, 0, width=4, order="dfs",
-                               budget_s=budget_s, max_evals=max_evals,
-                               surrogate=scorer)
-            res = res2 if res2.best_gflops > res.best_gflops else res
-            best_g, actions, nest = res.best_gflops, res.actions, res.best_nest
-        else:  # default / untuned
-            env.reset(0)
-            best_g, actions, nest = env.current_gflops, [], env.nest.clone()
-        # bank speculative measure-ahead work: anything the searches put in
-        # flight on an async farm but never collected still lands in the
-        # shared cache (a later tune() call may hit it for free)
-        self.cache.drain_ahead()
-        entry = self._record(kernel, bench, best_g, list(actions), nest, dtype)
-        entry["tune_time_s"] = time.perf_counter() - t0
+        """Tune one contraction; returns the registry entry.  Its
+        ``looptune.contraction`` span's self time is the search's own host
+        work: measuring, compiling and operands open spans of their own."""
+        with timed("looptune.contraction") as sp:
+            budget_s = budget_s if budget_s is not None else self.search_budget_s
+            env = self._env_for(bench)
+            if self.policy == "policy":
+                best_g, actions, nest = greedy_rollout(env, self.act, 0)
+            elif self.policy == "search":
+                scorer = self._scorer_for(env)
+                res = greedy_search(env, 0, lookahead=1, budget_s=budget_s,
+                                    max_evals=max_evals, surrogate=scorer)
+                res2 = beam_search(env, 0, width=4, order="dfs",
+                                   budget_s=budget_s, max_evals=max_evals,
+                                   surrogate=scorer)
+                res = res2 if res2.best_gflops > res.best_gflops else res
+                best_g, actions, nest = res.best_gflops, res.actions, res.best_nest
+            else:  # default / untuned
+                env.reset(0)
+                best_g, actions, nest = env.current_gflops, [], env.nest.clone()
+            # bank speculative measure-ahead work: anything the searches put in
+            # flight on an async farm but never collected still lands in the
+            # shared cache (a later tune() call may hit it for free)
+            self.cache.drain_ahead()
+            entry = self._record(kernel, bench, best_g, list(actions), nest, dtype)
+        entry["tune_time_s"] = sp.seconds
         entry["base_gflops"] = env.initial_gflops
         return entry
 

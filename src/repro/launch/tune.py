@@ -43,6 +43,7 @@ from repro.core.registry import ScheduleRegistry
 from repro.core.rl_common import epsilon_ladder
 from repro.core.tuner import LoopTuner
 from repro.runtime.device import enable_compile_cache
+from repro.runtime.spans import span, timed
 
 
 class TuneJournal:
@@ -209,7 +210,8 @@ def tune_records(
         # flush, not save: concurrent fleet shards (and a farm-side merge)
         # must not lose each other's records
         if flush_path:
-            registry.flush(flush_path)
+            with span("looptune.registry.flush"):
+                registry.flush(flush_path)
 
     tuner.tune_many(
         [matmul_benchmark(kept[i]["m"], kept[i]["k"], kept[i]["n"])
@@ -382,101 +384,106 @@ def tune_model(
     instead of re-tracing them.  Returns a report dict (harvested/tuned
     counts, per-entry summaries, coverage of the executed FLOPs).
     """
-    t0 = time.perf_counter()
-    cfg = get_config(cfg_or_arch) if isinstance(cfg_or_arch, str) else cfg_or_arch
-    if smoke and not cfg.name.endswith("-smoke"):
-        cfg = cfg.smoke()
-    if fleet > 1 and (farm is None or tuner is not None):
-        raise ValueError("--fleet N needs --farm (N clients share one "
-                         "measurement farm) and builds its own per-client "
-                         "tuners")
-    if registry is None:
-        registry = ScheduleRegistry(registry_path)
-    owns_backend = tuner is None and fleet <= 1
-    if owns_backend:
-        # --farm: timings come from a remote measurement farm; ``backend``
-        # becomes the local fallback the client degrades to if the farm is
-        # unreachable (a tune is never failed by the farm)
-        tune_backend = (make_backend("remote", addr=farm, fallback=backend)
-                        if farm is not None else backend)
-        if checkpoint is not None:
-            tuner = LoopTuner.from_checkpoint(checkpoint, backend=tune_backend,
-                                              registry=registry,
-                                              cache_dir=kernel_cache)
-        else:
-            tuner = LoopTuner(policy=policy, backend=tune_backend,
-                              registry=registry, cache_dir=kernel_cache)
-
-    records = harvest_model(cfg, batch=batch, prompt_len=prompt_len,
-                            max_len=max_len, kinds=kinds)
-    kept = records[:max_contractions]
-    share_kept = sum(r["flop_share"] for r in kept)
-
-    journal = TuneJournal(journal_path) if journal_path else None
-    fleet_report: Optional[Dict[str, Any]] = None
-    if fleet > 1:
-        entries, n_skipped, clients = tune_records_fleet(
-            kept, n_clients=fleet, farm=farm, backend=backend,
-            policy=policy, checkpoint=checkpoint,
-            registry_path=registry_path, budget_s=budget_s,
-            eval_budget=eval_budget, journal=journal, resume=resume,
-            kernel_cache=kernel_cache)
-        # fleet-mode flushes land per client; re-read so report counts and
-        # a final save reflect the merged table
-        if registry_path and os.path.exists(registry_path):
+    with timed("looptune.tune_model") as sp:
+        cfg = get_config(cfg_or_arch) if isinstance(cfg_or_arch, str) else cfg_or_arch
+        if smoke and not cfg.name.endswith("-smoke"):
+            cfg = cfg.smoke()
+        if fleet > 1 and (farm is None or tuner is not None):
+            raise ValueError("--fleet N needs --farm (N clients share one "
+                             "measurement farm) and builds its own per-client "
+                             "tuners")
+        if registry is None:
             registry = ScheduleRegistry(registry_path)
-        fleet_report = {
-            "n_clients": fleet,
-            "clients": clients,
-            # farm totals across the fleet: the aggregate pipelining view
-            "tickets_submitted": sum(
-                c["farm"].get("tickets_submitted", 0) for c in clients),
-            "tickets_collected": sum(
-                c["farm"].get("tickets_collected", 0) for c in clients),
-            "tickets_resubmitted": sum(
-                c["farm"].get("tickets_resubmitted", 0) for c in clients),
-        }
-    else:
-        entries, n_skipped = tune_records(
-            kept, tuner=tuner, registry=registry, registry_path=registry_path,
-            budget_s=budget_s, eval_budget=eval_budget,
-            journal=journal, resume=resume)
+        owns_backend = tuner is None and fleet <= 1
+        if owns_backend:
+            # --farm: timings come from a remote measurement farm; ``backend``
+            # becomes the local fallback the client degrades to if the farm is
+            # unreachable (a tune is never failed by the farm)
+            tune_backend = (make_backend("remote", addr=farm, fallback=backend)
+                            if farm is not None else backend)
+            if checkpoint is not None:
+                tuner = LoopTuner.from_checkpoint(checkpoint, backend=tune_backend,
+                                                  registry=registry,
+                                                  cache_dir=kernel_cache)
+            else:
+                tuner = LoopTuner(policy=policy, backend=tune_backend,
+                                  registry=registry, cache_dir=kernel_cache)
 
-    path = registry_path or registry.path
-    if path:
-        registry.flush(path)
-    tb = tuner.backend if tuner is not None else None
-    compile_stats = getattr(tb, "compile_stats", None)
-    farm_stats = getattr(tb, "farm_stats", None)
-    report = {
-        "arch": cfg.name,
-        "kinds": list(kinds),
-        "shapes": {"batch": batch, "prompt_len": prompt_len,
-                   "max_len": max_len},
-        "n_harvested": len(records),
-        "n_tuned": len(entries),
-        "n_skipped": n_skipped,
-        "resumed": bool(resume),
-        "journal": journal_path,
-        "flop_share_covered": share_kept,
-        "registry_size": len(registry),
-        "registry_path": registry_path or registry.path,
-        "kernel_cache": kernel_cache,
-        "compile": compile_stats() if compile_stats is not None else None,
-        "farm": farm_stats() if farm_stats is not None else None,
-        "fleet": fleet_report,
-        "tune_time_s": round(time.perf_counter() - t0, 2),
-        "contractions": [
-            {"m": r["m"], "k": r["k"], "n": r["n"], "dtype": r["dtype"],
-             "count": r["count"], "flop_share": round(r["flop_share"], 4),
-             "gflops": e.get("gflops"),
-             "base_gflops": e.get("base_gflops"),
-             "resumed": bool(e.get("resumed", False))}
-            for r, e in zip(kept, entries)
-        ],
-    }
-    if owns_backend:
-        tb.close()  # compile-ahead thread, worker pool, farm connection
+        with span("looptune.harvest"):
+            records = harvest_model(cfg, batch=batch, prompt_len=prompt_len,
+                                    max_len=max_len, kinds=kinds)
+        kept = records[:max_contractions]
+        share_kept = sum(r["flop_share"] for r in kept)
+
+        journal = TuneJournal(journal_path) if journal_path else None
+        fleet_report: Optional[Dict[str, Any]] = None
+        if fleet > 1:
+            entries, n_skipped, clients = tune_records_fleet(
+                kept, n_clients=fleet, farm=farm, backend=backend,
+                policy=policy, checkpoint=checkpoint,
+                registry_path=registry_path, budget_s=budget_s,
+                eval_budget=eval_budget, journal=journal, resume=resume,
+                kernel_cache=kernel_cache)
+            # fleet-mode flushes land per client; re-read so report counts and
+            # a final save reflect the merged table
+            if registry_path and os.path.exists(registry_path):
+                registry = ScheduleRegistry(registry_path)
+            fleet_report = {
+                "n_clients": fleet,
+                "clients": clients,
+                # farm totals across the fleet: the aggregate pipelining view
+                "tickets_submitted": sum(
+                    c["farm"].get("tickets_submitted", 0) for c in clients),
+                "tickets_collected": sum(
+                    c["farm"].get("tickets_collected", 0) for c in clients),
+                "tickets_resubmitted": sum(
+                    c["farm"].get("tickets_resubmitted", 0) for c in clients),
+            }
+        else:
+            entries, n_skipped = tune_records(
+                kept, tuner=tuner, registry=registry, registry_path=registry_path,
+                budget_s=budget_s, eval_budget=eval_budget,
+                journal=journal, resume=resume)
+
+        path = registry_path or registry.path
+        if path:
+            with span("looptune.registry.flush"):
+                registry.flush(path)
+        tb = tuner.backend if tuner is not None else None
+        if owns_backend:
+            # compile-ahead thread, worker pool, farm connection; closed
+            # first, so the compile counts include the compile-ahead work
+            # the close waits for
+            tb.close()
+        compile_stats = getattr(tb, "compile_stats", None)
+        farm_stats = getattr(tb, "farm_stats", None)
+        report = {
+            "arch": cfg.name,
+            "kinds": list(kinds),
+            "shapes": {"batch": batch, "prompt_len": prompt_len,
+                       "max_len": max_len},
+            "n_harvested": len(records),
+            "n_tuned": len(entries),
+            "n_skipped": n_skipped,
+            "resumed": bool(resume),
+            "journal": journal_path,
+            "flop_share_covered": share_kept,
+            "registry_size": len(registry),
+            "registry_path": registry_path or registry.path,
+            "kernel_cache": kernel_cache,
+            "compile": compile_stats() if compile_stats is not None else None,
+            "farm": farm_stats() if farm_stats is not None else None,
+            "fleet": fleet_report,
+            "contractions": [
+                {"m": r["m"], "k": r["k"], "n": r["n"], "dtype": r["dtype"],
+                 "count": r["count"], "flop_share": round(r["flop_share"], 4),
+                 "gflops": e.get("gflops"),
+                 "base_gflops": e.get("base_gflops"),
+                 "resumed": bool(e.get("resumed", False))}
+                for r, e in zip(kept, entries)
+            ],
+        }
+    report["tune_time_s"] = round(sp.seconds, 2)
     return report
 
 
