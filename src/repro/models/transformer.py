@@ -33,7 +33,7 @@ from repro.configs.base import (
     LayerSpec,
     ModelConfig,
 )
-from repro.runtime.sharding import ashard
+from repro.runtime.sharding import ashard, keep_kv_layout
 from . import layers as L
 from . import mamba as M
 from . import moe as X
@@ -121,7 +121,15 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Tuple[Any, ...]:
-    """Decode cache: one entry per period position, leaves stacked (n_periods, ...)."""
+    """Decode cache: one entry per period position, leaves stacked (n_periods, ...).
+
+    Attention leaves ``k``/``v`` hold (n_periods, B, buf, H_kv, D), the
+    sequence on axis 2: a step writes its tokens' slots of one layer.
+    Every other leaf is a layer's whole state (Mamba ``h``/``conv``, RWKV
+    ``s``/``xt``/``xc``, cross-attention ``ck``/``cv``), written whole.
+    The layer scan carries these stacks and updates them in place
+    (:func:`_run_layers`), so a caller that donates the cache to the jitted
+    step gets the same buffers back."""
     dt = _dtype(cfg)
     np_, hd = cfg.n_periods, cfg.head_dim_
     caches = []
@@ -156,9 +164,30 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Tuple[Any, ...]:
 # ---------------------------------------------------------------------------
 
 
-def _apply_mixer(spec, p, cfg, h, cache, cache_len, positions, encoder, decode):
-    """Mixer on normed input ``h``.  Returns (out, new_cache)."""
-    new_cache = dict(cache) if cache is not None else None
+def _layer(leaf, i):
+    """Layer ``i``'s slice of a stacked cache leaf."""
+    return jax.lax.dynamic_index_in_dim(leaf, i, keepdims=False)
+
+
+def _put(leaf, i, value, *at):
+    """``leaf`` with ``value`` written into layer ``i``'s slice at offsets
+    ``at`` of the slice's leading axes (zero elsewhere): one
+    ``dynamic_update_slice`` of the stack, in place in the scan carry."""
+    start = (i, *at) + (0,) * (value.ndim - len(at))
+    return jax.lax.dynamic_update_slice(leaf, value[None], start)
+
+
+def _put_token(leaf, i, value, pos):
+    """:func:`_put` of one token's K or V at sequence position ``pos``,
+    the stack held to the layout it enters the step with
+    (:func:`~repro.runtime.sharding.keep_kv_layout`)."""
+    return keep_kv_layout(_put(leaf, i, value, 0, pos))
+
+
+def _apply_mixer(spec, p, cfg, h, cache, li, cache_len, positions, encoder,
+                 decode):
+    """Mixer on normed input ``h``.  Returns (out, cache): ``cache`` is the
+    period position's stacked cache with layer ``li``'s entries written."""
     if spec.mixer in (ATTN, ATTN_LOCAL, CROSS_ATTN):
         q, k, v = L.attn_qkv(p["attn"], cfg, h, positions=positions)
         window = spec.window if spec.mixer == ATTN_LOCAL else None
@@ -178,99 +207,102 @@ def _apply_mixer(spec, p, cfg, h, cache, cache_len, positions, encoder, decode):
             out = full_seq_attn(q, k, v)
         elif not decode:  # prefill: run full attention, fill the cache
             out = full_seq_attn(q, k, v)
-            buf = cache["k"].shape[1]
-            s = k.shape[1]
-            if buf >= s:
-                new_cache["k"] = jax.lax.dynamic_update_slice(
-                    cache["k"], k, (0, 0, 0, 0))
-                new_cache["v"] = jax.lax.dynamic_update_slice(
-                    cache["v"], v, (0, 0, 0, 0))
-            else:  # windowed cache keeps only the tail
-                new_cache["k"] = k[:, -buf:]
-                new_cache["v"] = v[:, -buf:]
-        else:  # decode: append one token, attend over the cache
-            kc = jax.lax.dynamic_update_slice(
-                cache["k"], k, (0, cache_len, 0, 0))
-            vc = jax.lax.dynamic_update_slice(
-                cache["v"], v, (0, cache_len, 0, 0))
-            new_cache["k"], new_cache["v"] = kc, vc
-            out = L.attention(q, kc, vc, causal=True, q_offset=cache_len,
-                              kv_len=cache_len + 1, window=window,
-                              softcap=cfg.attn_softcap)
+            # the layer's whole slice, zeros past the prompt: decode reads
+            # the masked slots too, and XLA on TPU may hand the scan a
+            # fresh stack it never filled with init_cache's zeros
+            buf = cache["k"].shape[2]
+            k, v = (x[:, -buf:] if buf < x.shape[1]  # windowed: the tail
+                    else jnp.pad(x, ((0, 0), (0, buf - x.shape[1]),
+                                     (0, 0), (0, 0)))
+                    for x in (k, v))
+            cache = dict(cache, k=_put(cache["k"], li, k),
+                         v=_put(cache["v"], li, v))
+        else:  # decode: write one token's slot, attend over the layer
+            cache = dict(cache, k=_put_token(cache["k"], li, k, cache_len),
+                         v=_put_token(cache["v"], li, v, cache_len))
+            out = L.attention(q, _layer(cache["k"], li),
+                              _layer(cache["v"], li), causal=True,
+                              q_offset=cache_len, kv_len=cache_len + 1,
+                              window=window, softcap=cfg.attn_softcap)
         if spec.mixer == CROSS_ATTN:
             out = L.dense(out.reshape(*h.shape[:2], -1), p["attn"]["wo"])
             hx = L.rms_norm(h + out.astype(h.dtype), p["norm_cross"])
             if decode:
-                ck, cv = cache["ck"], cache["cv"]
+                ck, cv = _layer(cache["ck"], li), _layer(cache["cv"], li)
                 qx = L.dense(hx, p["cross"]["wq"]).reshape(
                     *hx.shape[:2], cfg.n_heads, cfg.head_dim_)
             else:
                 qx, ck, cv = L.attn_qkv(p["cross"], cfg, hx, kv_src=encoder,
                                         rope=False)
-                if new_cache is not None:
-                    new_cache["ck"], new_cache["cv"] = ck, cv
+                if cache is not None:
+                    cache = dict(cache, ck=_put(cache["ck"], li, ck),
+                                 cv=_put(cache["cv"], li, cv))
             xout = L.attention(qx, ck, cv, causal=False)
             return (out + L.dense(xout.reshape(*h.shape[:2], -1),
-                                  p["cross"]["wo"]).astype(out.dtype)), new_cache
+                                  p["cross"]["wo"]).astype(out.dtype)), cache
         return L.dense(out.reshape(*h.shape[:2], -1),
-                       p["attn"]["wo"]), new_cache
+                       p["attn"]["wo"]), cache
 
     if spec.mixer == MAMBA:
-        st = (M.MambaState(cache["h"], cache["conv"]) if cache is not None else None)
+        st = (M.MambaState(_layer(cache["h"], li), _layer(cache["conv"], li))
+              if cache is not None else None)
         if decode:
             out, st2 = M.mamba_decode(p["mamba"], h, st)
         else:
-            out, st2 = M.mamba_apply(p["mamba"], h, st if cache is not None else None)
-        if new_cache is not None:
-            new_cache["h"], new_cache["conv"] = st2.h, st2.conv
-        return out, new_cache
+            out, st2 = M.mamba_apply(p["mamba"], h, st)
+        if cache is not None:
+            cache = dict(cache, h=_put(cache["h"], li, st2.h),
+                         conv=_put(cache["conv"], li, st2.conv))
+        return out, cache
 
     if spec.mixer == RWKV6:
         if decode:
             out, s2, xt = R.time_mix_decode(
-                p["rwkv"], h, cfg.rwkv_head_dim, cache["s"], cache["xt"])
+                p["rwkv"], h, cfg.rwkv_head_dim, _layer(cache["s"], li),
+                _layer(cache["xt"], li))
         else:
-            s0 = cache["s"] if cache is not None else None
-            xp = cache["xt"] if cache is not None else None
+            s0 = _layer(cache["s"], li) if cache is not None else None
+            xp = _layer(cache["xt"], li) if cache is not None else None
             out, s2, xt = R.time_mix_chunked(
                 p["rwkv"], h, cfg.rwkv_head_dim, state=s0, x_prev=xp)
-        if new_cache is not None:
-            new_cache["s"], new_cache["xt"] = s2, xt
-        return out, new_cache
+        if cache is not None:
+            cache = dict(cache, s=_put(cache["s"], li, s2),
+                         xt=_put(cache["xt"], li, xt))
+        return out, cache
 
     raise ValueError(spec.mixer)
 
 
-def _apply_ffn(spec, p, cfg, h, cache, decode):
-    new_cache = cache
+def _apply_ffn(spec, p, cfg, h, cache, li, decode):
     aux = None
     if spec.ffn == MOE:
         out, aux = X.moe_apply(p["moe"], h, cfg.moe, cfg.act)
     elif spec.mixer == RWKV6:
-        xc = cache["xc"] if (cache is not None and decode) else None
+        xc = _layer(cache["xc"], li) if (cache is not None and decode) else None
         out, last = R.channel_mix(p["cmix"], h, x_prev=xc)
         if cache is not None:
-            new_cache = dict(cache)
-            new_cache["xc"] = last
+            cache = dict(cache, xc=_put(cache["xc"], li, last))
     else:
         out = L.mlp_apply(p["mlp"], h, cfg.act)
-    return out, new_cache, aux
+    return out, cache, aux
 
 
-def _apply_block(spec, p, cfg, x, cache, cache_len, positions, encoder,
+def _apply_block(spec, p, cfg, x, cache, li, cache_len, positions, encoder,
                  decode, aux_acc):
     h = L.rms_norm(x, p["norm_attn"])
-    mix, new_cache = _apply_mixer(spec, p, cfg, h, cache, cache_len,
+    mix, new_cache = _apply_mixer(spec, p, cfg, h, cache, li, cache_len,
                                   positions, encoder, decode)
     if cfg.post_norm:
         mix = L.rms_norm(mix, p["post_attn"])
     if cfg.parallel_block:
-        ff, new_cache, aux = _apply_ffn(spec, p, cfg, h, new_cache, decode)
+        ff, new_cache, aux = _apply_ffn(spec, p, cfg, h, new_cache, li,
+                                        decode)
         x = x + mix.astype(x.dtype) + ff.astype(x.dtype)
     else:
         x = x + mix.astype(x.dtype)
         h2 = L.rms_norm(x, p["norm_ffn"])
-        ff, new_cache, aux = _apply_ffn(spec, p, cfg, h2, new_cache, decode)
+        ff, new_cache, aux = _apply_ffn(spec, p, cfg, h2, new_cache, li,
+                                        decode)
         if cfg.post_norm:
             ff = L.rms_norm(ff, p["post_ffn"])
         x = x + ff.astype(x.dtype)
@@ -296,13 +328,23 @@ def _embed_in(params, cfg, batch) -> jax.Array:
 
 def _run_layers(params, cfg, x, caches, cache_len, positions, encoder,
                 decode, remat=True):
+    """Scan the layer periods over ``x``.  Returns (x, caches, aux).
+
+    The stacked caches travel in the scan carry with the period index, not
+    as scanned inputs and outputs: layer ``i`` reads its slice of each
+    stack and writes its entries back with one ``dynamic_update_slice``
+    (a decode step: one token's K/V at ``cache_len``; prefill: the
+    prompt's block; states: the layer's whole slice).  XLA aliases a
+    while-loop carry updated that way, so the returned caches are the
+    input buffers when the caller donates them; without donation the
+    step copies the cache once on entry."""
     n_specs = len(cfg.period)
     policy = (cfg.remat_policy if remat is True
               else (remat if isinstance(remat, str) else "none"))
 
     def make_block_fn(spec):
-        def f(p, x, cache, aux, cache_len, positions, encoder):
-            return _apply_block(spec, p, cfg, x, cache, cache_len,
+        def f(p, x, cache, li, aux, cache_len, positions, encoder):
+            return _apply_block(spec, p, cfg, x, cache, li, cache_len,
                                 positions, encoder, decode, aux)
         return f
 
@@ -314,22 +356,20 @@ def _run_layers(params, cfg, x, caches, cache_len, positions, encoder,
         # (decisive for wide heterogeneous periods, EXPERIMENTS §Perf).
         block_fns = [jax.checkpoint(f) for f in block_fns]
 
-    def period_body(carry, xs):
-        x, aux = carry
-        blocks = xs[:n_specs]
-        pcaches = xs[n_specs:] if caches is not None else (None,) * n_specs
-        new_caches = []
+    def period_body(carry, blocks):
+        x, aux, li, pcaches = carry
+        pcaches = list(pcaches)
         for pos in range(n_specs):
-            x, nc, aux = block_fns[pos](
-                blocks[pos], x, pcaches[pos], aux, cache_len, positions,
+            x, pcaches[pos], aux = block_fns[pos](
+                blocks[pos], x, pcaches[pos], li, aux, cache_len, positions,
                 encoder)
-            new_caches.append(nc if nc is not None else {})
-        return (x, aux), tuple(new_caches)
+        return (x, aux, li + 1, tuple(pcaches)), None
 
     body = jax.checkpoint(period_body) if policy == "period" else period_body
-    xs = params["blocks"] + (caches if caches is not None else ())
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs, length=cfg.n_periods)
+    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
+             caches if caches is not None else (None,) * n_specs)
+    (x, aux, _, new_caches), _ = jax.lax.scan(
+        body, carry, params["blocks"], length=cfg.n_periods)
     return x, (new_caches if caches is not None else None), aux
 
 
@@ -380,7 +420,12 @@ def decode_step(
     caches: Tuple,
     cache_len: jax.Array,             # i32 scalar: valid cache length
 ) -> Tuple[jax.Array, Tuple]:
-    """One decode step.  Returns (logits (B, 1, V), new_caches)."""
+    """One decode step.  Returns (logits (B, 1, V), new_caches).
+
+    Writes the token's K/V into slot ``cache_len`` of each attention layer
+    and each layer's new state, in place in the layer scan's carry: jit
+    the step with the cache donated (``donate_argnums``) and the new
+    caches reuse its buffers, with no copy of a layer or of the stack."""
     x = _embed_in(params, cfg, batch)
     positions = jnp.full((1, 1), cache_len, jnp.int32)
     x, new_caches, _ = _run_layers(

@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _TLS = threading.local()
@@ -253,48 +254,57 @@ def fsdp_pspecs(param_specs: Any, shapes: Any, mesh: Mesh,
 # ---------------------------------------------------------------------------
 
 
+def _batch_axis(mesh: Mesh, rules, batch: int):
+    """(the mesh axes a cache's batch dim shards over or None, whether the
+    batch divides over the batch axes)."""
+    axes = tuple(a for a in rules["batch"] if a in mesh.axis_names)
+    bsize = math.prod(mesh.shape[a] for a in axes) if axes else 1
+    ok = batch % bsize == 0 and batch >= bsize
+    return ((axes if len(axes) > 1 else axes[0]) if ok and axes else None), ok
+
+
+def kv_pspec(mesh: Mesh, shape: Sequence[int], kv_heads: int,
+             rules: Optional[Dict] = None) -> P:
+    """Spec of a K/V cache stack (n_periods, B, T, HKV, D).  Preference
+    order for the model axis: KV heads when divisible, else the sequence
+    dim (the decode path reduces over T with plain all-reduces).  batch=1
+    long-context shards T over data as well."""
+    rules = dict(rules or DEFAULT_RULES)
+    b_ax, batch_ok = _batch_axis(mesh, rules, shape[1])
+    model_size = math.prod(
+        mesh.shape[a] for a in rules["model"] if a in mesh.axis_names) or 1
+    head_ax = "model" if kv_heads % model_size == 0 else None
+    seq_parts = []
+    if not batch_ok:
+        seq_parts += list(a for a in rules["seq"] if a in mesh.axis_names)
+    if head_ax is None:
+        seq_parts += list(a for a in rules["act_seq"] if a in mesh.axis_names)
+    seq_ax = None
+    if seq_parts:
+        prod = math.prod(mesh.shape[a] for a in seq_parts)
+        if shape[2] % prod == 0:
+            seq_ax = tuple(seq_parts) if len(seq_parts) > 1 else seq_parts[0]
+        else:
+            _record_fallback(
+                f"cache seq {shape[2]} % {prod} != 0 -> replicated")
+    return P(None, b_ax, seq_ax, _resolve(mesh, rules, head_ax, shape[3])
+             if head_ax else None, None)
+
+
 def cache_pspecs(cache_tree: Any, mesh: Mesh, batch: int,
                  kv_heads: int, rules: Optional[Dict] = None) -> Any:
     """Decode-cache specs.  Normal decode: batch over (pod, data), heads over
     model.  batch=1 long-context: sequence dim over data (flash-decode style;
     GSPMD inserts the partial-softmax combine collectives)."""
     rules = dict(rules or DEFAULT_RULES)
-    batch_axes = tuple(a for a in rules["batch"] if a in mesh.axis_names)
-    bsize = math.prod(mesh.shape[a] for a in batch_axes) if batch_axes else 1
-    batch_ok = batch % bsize == 0 and batch >= bsize
-    model_size = math.prod(
-        mesh.shape[a] for a in rules["model"] if a in mesh.axis_names) or 1
+    b_ax, _ = _batch_axis(mesh, rules, batch)
 
     def one(path, leaf):
         ps = _path_str(path)
         shape = leaf.shape
         nd = len(shape)
-        b_ax = (batch_axes if len(batch_axes) > 1 else batch_axes[0]) \
-            if (batch_ok and batch_axes) else None
         if re.search(r"\.(k|v|ck|cv)$", ps) and nd == 5:
-            # (n_periods, B, T, HKV, D).  Preference order for the model
-            # axis: KV heads when divisible, else the sequence dim (the
-            # decode path reduces over T with plain all-reduces).  batch=1
-            # long-context shards T over data as well.
-            head_ax = "model" if kv_heads % model_size == 0 else None
-            seq_parts = []
-            if not batch_ok:
-                seq_parts += list(
-                    a for a in rules["seq"] if a in mesh.axis_names)
-            if head_ax is None:
-                seq_parts += list(
-                    a for a in rules["act_seq"] if a in mesh.axis_names)
-            seq_ax = None
-            if seq_parts:
-                prod = math.prod(mesh.shape[a] for a in seq_parts)
-                if shape[2] % prod == 0:
-                    seq_ax = tuple(seq_parts) if len(seq_parts) > 1 \
-                        else seq_parts[0]
-                else:
-                    _record_fallback(
-                        f"cache seq {shape[2]} % {prod} != 0 -> replicated")
-            return P(None, b_ax, seq_ax, _resolve(mesh, rules, head_ax, shape[3])
-                     if head_ax else None, None)
+            return kv_pspec(mesh, shape, kv_heads, rules)
         if re.search(r"\.(h|conv)$", ps) and nd >= 3:
             # mamba: (n_periods, B, ..., d_inner[, N]) — d_inner over model
             inner_axis = 2 if ps.endswith(".h") else 3
@@ -312,6 +322,42 @@ def cache_pspecs(cache_tree: Any, mesh: Mesh, batch: int,
         return P(*parts)
 
     return jax.tree_util.tree_map_with_path(one, cache_tree)
+
+
+def keep_kv_layout(stack: jax.Array) -> jax.Array:
+    """A K/V cache stack (n_periods, B, T, HKV, D) held to the layout JAX
+    gives a jit argument of its shape, on the devices the step compiles
+    for: each shard of the :func:`use_mesh` mesh (sharded by
+    :func:`kv_pspec`), else JAX's default device.  A loop carry so held
+    keeps the layout it enters the loop with; left free, XLA lays it out
+    for its uses inside the loop and copies the whole stack on entry and
+    exit.  A no-op where the device's client cannot say its default
+    layout."""
+    ctx = getattr(_TLS, "ctx", None)
+    mesh = ctx[0] if ctx else None
+    if mesh is None:
+        dev = jax.config.jax_default_device or jax.devices()[0]
+        shard = stack.shape
+    else:
+        spec = kv_pspec(mesh, stack.shape, stack.shape[3], ctx[1])
+        dev = mesh.devices.flat[0]
+        shard = NamedSharding(mesh, spec).shard_shape(stack.shape)
+    try:
+        layout = Layout.from_pjrt_layout(
+            dev.client.get_default_layout(stack.dtype, shard, dev))
+    except jax.errors.JaxRuntimeError as e:
+        if str(e).startswith("UNIMPLEMENTED"):
+            return stack
+        raise
+
+    def pin(x):
+        return with_layout_constraint(x, layout)
+
+    if mesh is None:
+        return pin(stack)
+    # inside a shard_map the partitioner leaves each shard where it is;
+    # outside, it would gather the stack around the constraint
+    return jax.shard_map(pin, mesh=mesh, in_specs=spec, out_specs=spec)(stack)
 
 
 def batch_pspec(mesh: Mesh, batch: int, ndim: int,

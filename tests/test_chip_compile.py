@@ -8,7 +8,9 @@ v5e topology is described inside a module fixture, so only the worker that
 runs this file loads the TPU library.  The CPU tests cover the legality
 check, the compile-cache placement and the harvest's platform independence.
 """
+import dataclasses
 import os
+import re
 import subprocess
 import sys
 
@@ -18,6 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import ARCHS
 from repro.core.actions import CPU_SPLITS, apply_action, build_action_space
 from repro.core.loop_ir import LoopNest, matmul_benchmark
 from repro.core.registry import schedule_to_blockspec
@@ -25,6 +28,7 @@ from repro.core.tiling import (VMEM_LIMIT_BYTES, block_error,
                                legalize_block)
 from repro.kernels.matmul import matmul
 from repro.runtime import device as D
+from repro.runtime import sharding as SH
 
 # musicgen-large's dense dots at batch 4, prompt 32 (launch.tune harvest):
 # decode m = 4, prefill m = 4 * 32
@@ -121,6 +125,111 @@ def test_routed_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     stats = ops.serving_stats(reset=True)
     assert stats["misses"] == 0 and stats["routed"] == stats["hits"] > 0
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _cache_copies(text, *dims):
+    """Copies and gathers in optimized HLO ``text`` of an array whose
+    shape ends in one of ``dims`` (K/V stacks, their shards, one layer's
+    slice)."""
+    ends = "|".join(",".join(map(str, d)) for d in dims)
+    return [ln for ln in text.splitlines()
+            if re.search(r"= \S+ (copy|all-gather)(-start|-done)?\(", ln)
+            and re.search(rf"\[(\d+,)*({ends})\]", ln)]
+
+
+@pytest.mark.parametrize("arch,periods,batch,max_len", [
+    ("musicgen-large", None, 16, 512),   # served shapes; head dim 64
+    ("gemma2-27b", 2, 8, 512),           # head dim 128, windowed layers
+])
+def test_decode_step_keeps_the_cache_in_place_on_v5e(topo, one_chip, arch,
+                                                     periods, batch, max_len):
+    """A decode step, cache donated, compiled for v5e as its default
+    device: the K/V stacks keep the layout they enter with, so no copy of
+    a stack or of a layer's slice is made."""
+    from repro.configs import ShapeCell, get_config, input_specs
+    from repro.models import steps as S
+    from repro.models import transformer as T
+
+    cfg = get_config(arch)
+    if periods:
+        cfg = dataclasses.replace(cfg, n_layers=periods * len(cfg.period))
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    specs = input_specs(cfg, ShapeCell("serve", max_len, batch, "decode"))
+    with jax.default_device(topo.devices[0]):
+        compiled = jax.jit(S.make_decode_step(cfg),
+                           donate_argnums=(2,)).lower(
+            params, place(specs["batch"]), place(specs["caches"]),
+            place(specs["cache_len"])).compile()
+    stack = specs["caches"][0]["k"].shape
+    assert _cache_copies(compiled.as_text(), stack[1:]) == []
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_kv_layout_is_the_v5e_argument_layout(topo, one_chip, arch):
+    """The layout keep_kv_layout holds each K/V stack to is the one v5e
+    gives the stack as a step's argument, at two serving sizes: a step
+    that only holds its donated argument compiles to no copy.  A layout
+    that differed would copy the whole stack into and out of every
+    decode step."""
+    from repro.configs import get_config
+    from repro.models import transformer as T
+
+    cfg = get_config(arch)
+    for batch, max_len in [(8, 512), (16, 1040)]:
+        caches = jax.eval_shape(lambda: T.init_cache(cfg, batch, max_len))
+        for pos in caches:
+            if "k" not in pos:
+                continue
+            leaf = jax.ShapeDtypeStruct(pos["k"].shape, pos["k"].dtype,
+                                        sharding=one_chip)
+            with jax.default_device(topo.devices[0]):
+                text = jax.jit(SH.keep_kv_layout, donate_argnums=(0,)) \
+                    .lower(leaf).compile().as_text()
+            assert _cache_copies(text, leaf.shape) == [], leaf.shape
+
+
+def test_sharded_decode_step_keeps_cache_shards_in_place_on_v5e(topo,
+                                                                one_chip):
+    """musicgen-large's decode step on the v5e:2x2 mesh (data 2, model 2)
+    under use_mesh, the cache sharded by cache_pspecs and donated: each
+    device holds its shard of a K/V stack in place, with no copy and no
+    gather of a stack, a shard or a layer's slice.  Held outside a
+    shard_map, the partitioner gathers the stacks; left free, XLA copies
+    each shard into and out of the layer loop."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import ShapeCell, get_config, input_specs
+    from repro.models import steps as S
+    from repro.models import transformer as T
+
+    cfg = get_config("musicgen-large")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+    def place(tree, spec):
+        return jax.tree.map(lambda s, p: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, p)), tree, spec)
+
+    specs = input_specs(cfg, ShapeCell("serve", 512, 16, "decode"))
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    cspecs = SH.cache_pspecs(specs["caches"], mesh, 16, cfg.n_kv_heads)
+    with mesh, SH.use_mesh(mesh):
+        compiled = jax.jit(S.make_decode_step(cfg),
+                           donate_argnums=(2,)).lower(
+            place(params, jax.tree.map(lambda _: P(), params)),
+            place(specs["batch"], jax.tree.map(lambda _: P("data"),
+                                               specs["batch"])),
+            place(specs["caches"], cspecs),
+            place(specs["cache_len"], P())).compile()
+    stack = specs["caches"][0]["k"].shape                  # (48, 16, 512, 32, 64)
+    shard = (stack[1] // 2, stack[2], stack[3] // 2, stack[4])
+    assert cspecs[0]["k"] == P(None, "data", None, "model", None)
+    assert _cache_copies(compiled.as_text(), stack[1:], shard) == []
 
 
 def test_harvest_is_platform_independent(one_chip):
