@@ -57,7 +57,7 @@ def serve_readings(run, model_cfg, seeds, seconds):
     if not os.path.exists(path):
         harness.tune_for_serving(model_cfg, run.traffic, path)
     loop = ServeLoop(run.cfg, model_cfg, run.traffic, seeds[0],
-                     ScheduleRegistry(path))
+                     ScheduleRegistry(path), run.spec.block(run.cfg))
     out = []
     for seed in seeds:
         loop.seed, loop.key = seed, correct.W.base_key(seed)

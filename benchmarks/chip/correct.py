@@ -2,11 +2,12 @@
 reference, each compared number beside its limit.
 
 Serve cells: after the window, a sample of the finished requests, drawn
-from the seed, goes through the float32 reference (``reference/dense.py``)
-over its prompt and the tokens it was served.  Decoding is greedy, so each
-served token should be the reference's best at its position up to
-rounding; the number compared is the widest gap by which a served token's
-reference logit lies below the reference's best logit there.
+from the seed, goes through the float32 reference of the configuration's
+block (``reference/<block>.py``) over its prompt and the tokens it was
+served.  Decoding is greedy, so each served token should be the
+reference's best at its position up to rounding; the number compared is
+the widest gap by which a served token's reference logit lies below the
+reference's best logit there.
 
 Tune cells: every entry of the last table goes through the serving route,
 ``kernels.ops.tuned_einsum`` with the compiled kernel, on bfloat16 operands
@@ -25,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import weights as W
-from reference import dense
 
 Numbers = Dict[str, Dict[str, float]]
 
@@ -43,9 +43,9 @@ def sample_requests(finished: List[dict], batch: int, n: int, seed: int
 
 def reference_gaps(cfg: dict, seed: int, loop, finished: List[dict],
                    picks: List[Tuple[int, int]], quant=None):
-    """Per picked request: (served-token gaps under the float32 reference,
-    and, with ``quant``, the gaps of the tokens the lower precision puts
-    first)."""
+    """Per picked request: (served-token gaps under the float32 reference
+    of the loop's block, and, with ``quant``, the gaps of the tokens the
+    lower precision puts first)."""
     tokens = {f["wave"]: f["tokens"] for f in finished}
     by_wave: Dict[int, List[int]] = defaultdict(list)
     for w, s in picks:
@@ -54,12 +54,12 @@ def reference_gaps(cfg: dict, seed: int, loop, finished: List[dict],
     for w, slots in sorted(by_wave.items()):
         served = tokens[w][np.asarray(slots)]
         seq, pos = loop.reference_inputs(w, slots, served)
-        ref = dense.logits(cfg, seed, seq, pos)
+        ref = loop.block.logits(cfg, seed, seq, pos)
         best = ref.max(axis=-1)
         got = np.take_along_axis(ref, served[..., None], -1)[..., 0]
         served_gaps.append(best - got)
         if quant is not None:
-            low = dense.logits(cfg, seed, seq, pos, quant=quant)
+            low = loop.block.logits(cfg, seed, seq, pos, quant=quant)
             first = np.take_along_axis(ref, low.argmax(-1)[..., None], -1)
             control_gaps.append(best - first[..., 0])
     return served_gaps, control_gaps

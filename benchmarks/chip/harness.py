@@ -1,13 +1,19 @@
 """The benchmark's runner, led by ``BENCHMARK.json`` and files found by name.
 
 A workload names a configuration and a traffic mix.  The configuration's
-file (``BENCHMARK.json`` gives its path) holds the sizes as run; the
-traffic mix is ``traffic/<name>.json``; the limits of the comparison that
-decides ``correct`` are ``limits/<workload>.json``; a per-layer metric is
-read by ``metrics/<name>.py``, whose ``read(run)`` returns a number or
-``None`` when the run holds nothing for it to read, in the cells that its
-``workloads`` lists.  Adding a cell or a metric adds files; no file here
-changes.
+file (``BENCHMARK.json`` gives its path) holds the sizes as run: each of
+its keys is a field of the program's ``ModelConfig``, set over the zoo's
+``arch`` entry, or one of :data:`META_KEYS`.  Its ``block`` names the
+module ``reference/<block>.py`` (``dense`` when the key is absent) that
+makes the weights, holds the plain reference and counts a step's work: a
+new block is one new file there with the four :data:`BLOCK_FUNCTIONS`,
+which ``reference/dense.py`` sets out.  The traffic mix is
+``traffic/<name>.json``; the limits of the comparison that decides
+``correct`` are ``limits/<workload>.json``; a per-layer metric is read by
+``metrics/<name>.py``, whose ``read(run)`` returns a number or ``None``
+when the run holds nothing for it to read, in the cells that its
+``workloads`` lists.  Adding a cell, a metric or a block adds files; no
+file here changes.
 
 The traffic's ``kind`` picks the loop: ``serve`` (``serve_loop.py``) or
 ``tune`` (``tune_loop.py``).
@@ -20,6 +26,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -30,10 +37,17 @@ from typing import Any, Callable, Dict, List, Optional
 HERE = os.path.dirname(os.path.abspath(__file__))
 #: where the data files live, relative to the checkout's root
 DATA = os.path.join("benchmarks", "chip")
-#: the fields of the program's model configuration a config file sets
+#: the fields of the program's model configuration every config file sets
 MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
               "d_ff", "vocab", "act", "frontend", "tie_embeddings",
               "rope_theta", "dtype")
+#: the keys of a config file that describe it and set no field of the
+#: program's model configuration (``norm_eps`` is the reference's)
+META_KEYS = ("name", "source", "paper", "arch", "block", "reduced",
+             "assumed", "departures", "norm_eps")
+#: what a block module under ``reference/`` provides
+BLOCK_FUNCTIONS = ("program_params", "logits", "sites", "model_flops")
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 
 
 class SpecError(Exception):
@@ -46,6 +60,13 @@ def _load_json(path: str) -> dict:
             return json.load(f)
     except FileNotFoundError:
         raise SpecError(f"missing file {path}") from None
+
+
+def _load_module(name: str, path: str):
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
 
 
 class Spec:
@@ -88,11 +109,21 @@ class Spec:
         path = os.path.join(self.data, "metrics", metric + ".py")
         if not os.path.exists(path):
             raise SpecError(f"no reader {path} for metric {metric!r}")
-        mod_spec = importlib.util.spec_from_file_location(
-            "metric_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read
+        return _load_module("metric_" + metric.replace(".", "_"), path).read
+
+    def block(self, cfg: dict):
+        """The block module a config file names: ``reference/<block>.py``,
+        ``dense`` when the file names none."""
+        name = cfg.get("block", "dense")
+        path = os.path.join(self.data, "reference", f"{name}.py")
+        if not NAME.fullmatch(name) or not os.path.exists(path):
+            raise SpecError(f"no block {path} for config {cfg.get('name')!r}")
+        mod = _load_module("block_" + name.replace(".", "_"), path)
+        missing = [f for f in BLOCK_FUNCTIONS
+                   if not callable(getattr(mod, f, None))]
+        if missing:
+            raise SpecError(f"block {path} lacks {missing}")
+        return mod
 
     def peaks(self, kind: str) -> dict:
         table = _load_json(os.path.join(self.data, "peaks.json"))
@@ -103,12 +134,36 @@ class Spec:
 
 
 def model_config(cfg: dict):
-    """The program's configuration object for a config file."""
-    from repro.configs import get_config
+    """The program's configuration object for a config file: the zoo's
+    ``arch`` entry with every field the file sets.  ``moe``, an object,
+    sets the fields of the entry's ``MoEConfig`` that it names (all that
+    have no default where the entry has none); ``period``, a list of
+    ``{mixer, ffn, window}``, is the whole period.  A key that is neither a
+    field nor one of :data:`META_KEYS`, or a missing one of
+    :data:`MODEL_KEYS`, fails the run."""
+    from repro.configs import ARCHS, LayerSpec, ModelConfig, MoEConfig
 
-    base = get_config(cfg["arch"])
-    return dataclasses.replace(base, name=cfg["name"],
-                               **{k: cfg[k] for k in MODEL_KEYS})
+    name = cfg.get("name")
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(cfg) - fields - set(META_KEYS))
+    missing = [k for k in ("name", "arch") + MODEL_KEYS if k not in cfg]
+    if unknown or missing:
+        raise SpecError(f"config {name!r}: unknown keys {unknown}, "
+                        f"missing keys {missing}")
+    if cfg["arch"] not in ARCHS:
+        raise SpecError(f"config {name!r}: unknown arch {cfg['arch']!r}")
+    base = ARCHS[cfg["arch"]]
+    values = {k: v for k, v in cfg.items() if k in fields}
+    try:
+        if values.get("moe") is not None:
+            values["moe"] = (MoEConfig(**values["moe"]) if base.moe is None
+                             else dataclasses.replace(base.moe,
+                                                      **values["moe"]))
+        if "period" in values:
+            values["period"] = tuple(LayerSpec(**s) for s in values["period"])
+    except TypeError as e:
+        raise SpecError(f"config {name!r}: {e}") from None
+    return dataclasses.replace(base, **values)
 
 
 def log(phase: str, **fields) -> None:
@@ -190,6 +245,7 @@ class Run:
         self.require_chip = require_chip
         self.state = os.path.join(root, DATA, "state", workload)
         self.cfg = self.spec.config(self.wl["config"])
+        self.block = None
         self.traffic = self.spec.traffic(self.wl["traffic"])
         self.limits = self.spec.limits(workload)
         self.setup_parts: Dict[str, float] = {}
@@ -222,6 +278,7 @@ class Run:
         use_compile_cache(jax, self.state,
                           on=kind != "tune" and dev["platform"] == "tpu")
         model_cfg = model_config(self.cfg)
+        self.block = self.spec.block(self.cfg)
         if kind == "serve":
             return self._serve(jax, dev, peaks, model_cfg, t)
         if kind == "tune":
@@ -242,7 +299,7 @@ class Run:
             t = self._mark("tune", t)
         _served_table(path)
         loop = ServeLoop(self.cfg, model_cfg, self.traffic, self.seed,
-                         ScheduleRegistry(path))
+                         ScheduleRegistry(path), self.block)
         loop.make_weights()
         t = self._mark("weights", t)
         K.reset_serving_stats()
@@ -273,9 +330,9 @@ class Run:
         numbers = correct.serve_numbers(self.cfg, self.seed, loop, res,
                                         self.traffic, self.limits)
         log("reference", seconds=time.perf_counter() - t0)
-        ctx = SimpleNamespace(cfg=self.cfg, traffic=self.traffic,
-                              steps=res["steps"], routed_keys=routed,
-                              peaks=peaks, trace=reduced)
+        ctx = SimpleNamespace(cfg=self.cfg, block=self.block,
+                              traffic=self.traffic, steps=res["steps"],
+                              routed_keys=routed, peaks=peaks, trace=reduced)
         return self._result(dev, peak, e2e, ctx, reduced, numbers,
                             attempted=res["admitted"], failed=0)
 
@@ -299,7 +356,8 @@ class Run:
         numbers = correct.tune_numbers(loop.last_registry, self.seed,
                                        self.limits, pallas)
         shutil.rmtree(loop.dir, ignore_errors=True)
-        ctx = SimpleNamespace(cfg=self.cfg, traffic=self.traffic, steps=None,
+        ctx = SimpleNamespace(cfg=self.cfg, block=self.block,
+                              traffic=self.traffic, steps=None,
                               routed_keys=set(), peaks=peaks, trace=reduced)
         return self._result(dev, peak, e2e, ctx, reduced, numbers,
                             attempted=len(res["tables"]), failed=0)

@@ -1,9 +1,10 @@
 """mfu: the served prefill and decode steps (``models/steps.py``) as a
 share of the chip's bf16 peak, in %.
 
-Model operations of every step in the traced window (``work.model_flops``:
-two per weight per token, the logits product, attention over the cache
-length actually filled) over the traced window's seconds times the peak.
+Model operations of every step in the traced window (the block's
+``model_flops``: for ``dense``, two per weight per token, the logits
+product, attention over the cache length actually filled) over the traced
+window's seconds times the peak.
 """
 
 

@@ -4,10 +4,11 @@ its roofline, in %.
 The least time the chip could take for the routed products of the traced
 steps, over the device time they took.  Each product's least time is the
 larger of its operations over the bf16 peak and its unpadded operands and
-output over HBM bandwidth (``work.py``), so the same work counts whatever
-block or padding the kernel uses.  In decode (m = batch) the bandwidth
-bound holds: about m operations per byte, far below the chip's 240.  In
-prefill (m = batch x prompt) the compute bound holds.
+output over HBM bandwidth (``work.py``, over the block's ``sites``), so the
+same work counts whatever block or padding the kernel uses.  In decode
+(m = batch) the bandwidth bound holds: about m operations per byte, far
+below the chip's 240.  In prefill (m = batch x prompt) the compute bound
+holds.
 
 The device time is the kernel's own events plus XLA's copies of each
 layer's weight out of the stacked parameters, which exist only to hand the
@@ -59,6 +60,6 @@ def read(run):
         return None
     staged_s = staging_seconds(run.trace.device_events,
                                routed_weights(run.routed_keys))
-    least = work.routed_least_seconds(run.cfg, run.steps, run.routed_keys,
-                                      run.peaks)
+    least = work.routed_least_seconds(run.block, run.cfg, run.steps,
+                                      run.routed_keys, run.peaks)
     return 100.0 * least / (kernel_s + staged_s)

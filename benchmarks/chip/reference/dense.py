@@ -1,15 +1,30 @@
-"""Plain float32 forward of the dense decoder block the benchmark serves.
+"""The dense block: attention and a gated MLP in every layer.
 
-Written from the block's definition, not from the program: RMSNorm, rotary
-positions on the two halves of each head, causal softmax attention, a gated
-MLP (SiLU or tanh-approximated GELU), a final RMSNorm and an untied LM
-head.  No kernels, no cache, no batching tricks; every product at
-``highest`` precision, so a TPU does not round float32 operands to
-bfloat16.  Each configuration file lists where this block departs from the
-published model.
+A block module is what a configuration file names with ``"block"``
+(absent: ``dense``); the harness loads ``reference/<block>.py`` and uses
+four functions of it, so a new block is one new file here:
+
+- ``program_params(cfg, seed)``: the program's parameter tree, every
+  weight made from the seed in the served dtype, in one jitted call;
+- ``logits(cfg, seed, inputs, positions, quant=None, rows=...)``: the plain
+  float32 reference, one layer's weights at a time, with ``quant="fp8"``
+  the control;
+- ``sites(cfg, m)``: ``[((m, k, n), calls per step, output itemsize)]`` of
+  the products that pass through the program's dense entry point
+  (``layers.dense`` and the LM head) in a step of ``m`` rows;
+- ``model_flops(cfg, batch, new_tokens, kv_len)``: a step's useful
+  operations.
+
+This block's reference is written from its definition, not from the
+program: RMSNorm, rotary positions on the two halves of each head, causal
+softmax attention, a gated MLP (SiLU or tanh-approximated GELU), a final
+RMSNorm and an untied LM head.  No kernels, no cache, no batching tricks;
+every product at ``highest`` precision, so a TPU does not round float32
+operands to bfloat16.  Each configuration file lists where this block
+departs from the published model.
 
 The stack runs one layer at a time, with that layer's weights made again
-from the seed (``weights.reference_layer``), so only one layer's float32
+from the seed (:func:`reference_layer`), so only one layer's float32
 weights are on the device at once.  ``quant="fp8"`` is the control: the
 same forward with every dense product's operands rounded to float8 (e4m3,
 one scale per row of the activations and per output column of the weight),
@@ -18,17 +33,90 @@ the precision below the served bfloat16.
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Sequence
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 import weights as W
+import work
 
 HIGHEST = jax.lax.Precision.HIGHEST
 FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
+
+
+#: the config file keys that size the stack
+SIZE_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
+             "d_ff", "vocab")
+
+
+# ---------------------------------------------------------------------------
+# weights
+# ---------------------------------------------------------------------------
+
+
+def layer_weights(cfg: dict, words, layer, dtype) -> dict:
+    """One layer's weights in the program's parameter layout."""
+    d, f = cfg["d_model"], cfg["d_ff"]
+    hq = cfg["n_heads"] * cfg["head_dim"]
+    hkv = cfg["n_kv_heads"] * cfg["head_dim"]
+    base = (jnp.asarray(layer, jnp.uint32) + 1) * 16
+
+    def leaf(i, shape, scale):
+        return W.uniform(words, base + i, shape, scale)
+
+    w = {
+        "norm_attn": 1.0 + leaf(0, (d,), 0.1),
+        "norm_ffn": 1.0 + leaf(1, (d,), 0.1),
+        "attn": {"wq": leaf(2, (d, hq), d ** -0.5),
+                 "wk": leaf(3, (d, hkv), d ** -0.5),
+                 "wv": leaf(4, (d, hkv), d ** -0.5),
+                 "wo": leaf(5, (hq, d), hq ** -0.5)},
+        "mlp": {"w_gate": leaf(6, (d, f), d ** -0.5),
+                "w_up": leaf(7, (d, f), d ** -0.5),
+                "w_down": leaf(8, (f, d), f ** -0.5)},
+    }
+    return jax.tree.map(lambda x: x.astype(dtype), w)
+
+
+def _items(cfg: dict):
+    return tuple((k, int(cfg[k])) for k in SIZE_KEYS)
+
+
+@partial(jax.jit, static_argnums=(0, 2))
+def _stack(cfg_items, words, dtype):
+    cfg = dict(cfg_items)
+    blocks = jax.lax.map(lambda i: layer_weights(cfg, words, i, dtype),
+                         jnp.arange(cfg["n_layers"], dtype=jnp.uint32))
+    top = W.top_weights(cfg, words, dtype)
+    return {"embed": {"table": top["table"]},
+            "final_norm": top["final_norm"],
+            "lm_head": top["lm_head"],
+            "blocks": (blocks,)}
+
+
+def program_params(cfg: dict, seed: int):
+    """Every weight of the stack, in the served dtype, in one jitted call:
+    the tree the program's steps take."""
+    return _stack(_items(cfg), W.seed_words(seed), jnp.dtype(cfg["dtype"]))
+
+
+@partial(jax.jit, static_argnums=(0, 3))
+def _one_layer(cfg_items, words, layer, dtype):
+    return layer_weights(dict(cfg_items), words, layer, dtype)
+
+
+def reference_layer(cfg: dict, seed: int, i: int) -> dict:
+    """Layer ``i``'s served values, as float32."""
+    return W.as_float32(_one_layer(_items(cfg), W.seed_words(seed),
+                                   jnp.uint32(i), jnp.dtype(cfg["dtype"])))
+
+
+# ---------------------------------------------------------------------------
+# the reference
+# ---------------------------------------------------------------------------
 
 
 def rms_norm(x, w, eps):
@@ -117,9 +205,56 @@ def logits(cfg: dict, seed: int, inputs, positions: Sequence[int],
     del x
     with jax.default_matmul_precision("highest"):
         for layer in range(cfg["n_layers"]):
-            w = W.reference_layer(cfg, seed, layer)
+            w = reference_layer(cfg, seed, layer)
             blocks = [_layer(shape, b, w, quant) for b in blocks]
         idx = jnp.asarray(list(positions), jnp.int32)
         out = [np.asarray(_head(b, top, idx, float(cfg["norm_eps"]), quant))
                for b in blocks]
     return np.concatenate(out, axis=0)
+
+
+# ---------------------------------------------------------------------------
+# the work of a step
+# ---------------------------------------------------------------------------
+
+
+def sites(cfg: dict, m: int) -> List[Tuple[Tuple[int, int, int], int, int]]:
+    """[((m, k, n), calls per step, output itemsize)] for one step whose
+    products have ``m`` rows (batch x new tokens): q/k/v/o, gate/up/down
+    and the LM head."""
+    d, f, v = cfg["d_model"], cfg["d_ff"], cfg["vocab"]
+    hq = cfg["n_heads"] * cfg["head_dim"]
+    hkv = cfg["n_kv_heads"] * cfg["head_dim"]
+    act = work.ITEMSIZE[cfg["dtype"]]
+    layers = cfg["n_layers"]
+    return [((m, d, hq), layers, act),       # q
+            ((m, d, hkv), 2 * layers, act),  # k, v
+            ((m, hq, d), layers, act),       # o
+            ((m, d, f), 2 * layers, act),    # gate, up
+            ((m, f, d), layers, act),        # down
+            ((m, d, v), 1, 4)]               # LM head, float32 logits
+
+
+def matmul_params(cfg: dict) -> int:
+    """Weights that take part in products per token, less the head."""
+    d, f = cfg["d_model"], cfg["d_ff"]
+    hq = cfg["n_heads"] * cfg["head_dim"]
+    hkv = cfg["n_kv_heads"] * cfg["head_dim"]
+    return cfg["n_layers"] * (d * hq + 2 * d * hkv + hq * d + 3 * d * f)
+
+
+def model_flops(cfg: dict, batch: int, new_tokens: int, kv_len: int) -> float:
+    """Useful operations of one step (the analysis module's formula: 2 per
+    weight per token, the logits product, and attention over the keys each
+    query sees): ``new_tokens`` per sequence, the last of them at position
+    ``kv_len - 1``.  Prefill is ``new_tokens == kv_len`` (causal: half the
+    query-key pairs); decode is one token against ``kv_len`` keys."""
+    tokens = batch * new_tokens
+    f = 2.0 * matmul_params(cfg) * tokens
+    f += 2.0 * cfg["d_model"] * cfg["vocab"] * tokens
+    if new_tokens == kv_len:
+        pairs = new_tokens * kv_len / 2.0
+    else:
+        pairs = new_tokens * kv_len
+    f += batch * 4.0 * pairs * cfg["n_heads"] * cfg["head_dim"] * cfg["n_layers"]
+    return f

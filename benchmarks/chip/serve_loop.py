@@ -8,7 +8,8 @@ one prefill per wave, then one decode step per token, and the host reads
 every token as a streaming server must.  A client sends its next request
 as soon as its last one finished.  Unlike ``serve_once``, the weights and
 the compiled steps are made once, in set-up, so the window times only
-serving.
+serving.  The configuration's block module makes the weights and counts
+each step's operations.
 """
 from __future__ import annotations
 
@@ -20,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import weights as W
-import work
 
 Span = Callable[[str], Any]
 #: the warm-up's prompts come from a wave index no window reaches
@@ -36,10 +36,10 @@ class ServeLoop:
     """One cell's served model: weights, compiled steps and the window."""
 
     def __init__(self, cfg: dict, model_cfg, traffic: dict, seed: int,
-                 registry):
+                 registry, block):
         from repro.models import steps as S
 
-        self.cfg, self.seed = cfg, seed
+        self.cfg, self.seed, self.block = cfg, seed, block
         self.key = W.base_key(seed)
         b, p = traffic["batch"], traffic["prompt_len"]
         self.batch, self.prompt_len = b, p
@@ -60,7 +60,7 @@ class ServeLoop:
     # -- set-up ---------------------------------------------------------------
 
     def make_weights(self) -> None:
-        self.params = W.program_params(self.cfg, self.seed)
+        self.params = self.block.program_params(self.cfg, self.seed)
         if self.embeds:
             self.codes = W.code_table(self.cfg, self.key)
         jax.block_until_ready((self.params, self.codes))
@@ -112,7 +112,8 @@ class ServeLoop:
                                                self._inputs(prompts))
                 tok = self.first_token(last)
             steps["prefill"]["count"] += 1
-            steps["prefill"]["flops"] += work.model_flops(self.cfg, b, p, p)
+            steps["prefill"]["flops"] += self.block.model_flops(self.cfg, b,
+                                                                p, p)
             with span("read_token"):
                 served = [np.asarray(tok)]
             t_last = time.perf_counter()
@@ -136,8 +137,8 @@ class ServeLoop:
                 t_last = t
                 emitted += b
                 steps["decode"]["count"] += 1
-                steps["decode"]["flops"] += work.model_flops(self.cfg, b, 1,
-                                                             p + i)
+                steps["decode"]["flops"] += self.block.model_flops(
+                    self.cfg, b, 1, p + i)
             del caches
             if len(served) == g:
                 finished.append({"wave": wave,

@@ -18,7 +18,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import harness
-import weights as W
 
 CHIP = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 16e9
@@ -75,7 +74,8 @@ def test_routed_steps_compile_and_fit(one_chip, monkeypatch, tmp_path, config,
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=one_chip), tree)
 
-    params = jax.eval_shape(lambda: W.program_params(cfg, 0))
+    block = harness.Spec(os.path.dirname(os.path.dirname(CHIP))).block(cfg)
+    params = jax.eval_shape(lambda: block.program_params(cfg, 0))
     assert (jax.tree.structure(params) == jax.tree.structure(jax.eval_shape(
         lambda: T.init_params(model_cfg, jax.random.PRNGKey(0)))))
     params = place(params)
@@ -89,12 +89,16 @@ def test_routed_steps_compile_and_fit(one_chip, monkeypatch, tmp_path, config,
     caches = place(jax.eval_shape(lambda: T.init_cache(model_cfg, b,
                                                        max_len)))
     ops.reset_serving_stats()
-    prefill = jax.jit(S.make_prefill_step(model_cfg, max_len, registry=reg)
-                      ).lower(params, prompt).compile()
-    decode = jax.jit(S.make_decode_step(model_cfg, registry=reg),
-                     donate_argnums=(2,)).lower(
-        params, one, caches,
-        place(jax.ShapeDtypeStruct((), "int32"))).compile()
+    # the decode step holds its K/V stacks to the default device's layout:
+    # the described chip's, not this host's
+    with jax.default_device(next(iter(one_chip.device_set))):
+        prefill = jax.jit(S.make_prefill_step(model_cfg, max_len,
+                                              registry=reg)
+                          ).lower(params, prompt).compile()
+        decode = jax.jit(S.make_decode_step(model_cfg, registry=reg),
+                         donate_argnums=(2,)).lower(
+            params, one, caches,
+            place(jax.ShapeDtypeStruct((), "int32"))).compile()
     stats = ops.serving_stats(reset=True)
     assert stats["misses"] == 0 and stats["routed"] == stats["hits"] > 0
     for name, c in (("prefill", prefill), ("decode", decode)):

@@ -26,24 +26,6 @@ def _run(root, workload, seed=2 ** 31 + 3, seconds=1.0):
                        time.perf_counter(), require_chip=False).execute()
 
 
-def _break_decode(monkeypatch, fault):
-    from repro.models import steps as S
-
-    make = S.make_decode_step
-
-    def broken(cfg, registry=None):
-        step = make(cfg, registry=registry)
-
-        def serve_step(params, batch, caches, cache_len):
-            nxt, logits, new = step(params, batch, caches, cache_len)
-            if fault == "state":
-                return nxt, logits, caches
-            return (nxt + 1) % cfg.vocab, logits, new
-        return serve_step
-
-    monkeypatch.setattr(S, "make_decode_step", broken)
-
-
 @pytest.mark.parametrize("workload", ["tiny-tokens.serve", "tiny-embeds.serve"])
 def test_sound_serve_run_is_correct(root, workload):
     out = _run(root, workload)
@@ -53,7 +35,7 @@ def test_sound_serve_run_is_correct(root, workload):
 @pytest.mark.parametrize("fault", ["state", "token"])
 @pytest.mark.parametrize("workload", ["tiny-tokens.serve", "tiny-embeds.serve"])
 def test_broken_decode_is_not_correct(root, monkeypatch, workload, fault):
-    _break_decode(monkeypatch, fault)
+    tiny.break_decode(monkeypatch, fault)
     out = _run(root, workload)
     assert out["correct"] is False, out["compared"]
 

@@ -13,9 +13,11 @@ from types import SimpleNamespace
 
 import pytest
 
+import harness
 import trace_reduce as tr
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(HERE)))
 METRICS = os.path.join(os.path.dirname(HERE), "metrics")
 SMALL = os.path.join(HERE, "data", "small.xplane.pb")
 READERS = ("tune.harvest_s", "tune.search_s", "tune.compile_s",
@@ -119,12 +121,12 @@ def test_recorded_trace_reads_as_before():
                        ["matmul.1 bf16[16,2048]", pytest.approx(5.1629e-05)]],
         "idle_gaps": [["read_token", pytest.approx(0.006899034)],
                       ["decode", pytest.approx(0.000231976)]]}
-    # one layer whose q, k, v and o are the trace's four 16x2048x2048
-    # products, all routed
+    # one dense layer whose q, k, v and o are the trace's four
+    # 16x2048x2048 products, all routed
     cfg = {"n_layers": 1, "d_model": 2048, "n_heads": 32, "n_kv_heads": 32,
            "head_dim": 64, "d_ff": 8192, "vocab": 2048, "dtype": "bfloat16"}
     run = SimpleNamespace(
-        cfg=cfg, traffic={}, trace=r,
+        cfg=cfg, block=harness.Spec(ROOT).block(cfg), traffic={}, trace=r,
         steps={"decode": {"m": 16, "count": 1, "flops": 1e9}},
         routed_keys={"mm:16x2048x2048:bfloat16"},
         peaks={"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9})

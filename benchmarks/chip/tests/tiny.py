@@ -18,6 +18,14 @@ CONFIGS = {
                         frontend="tokens"),
     "tiny-embeds": dict(TINY, arch="musicgen-large", act="gelu",
                         frontend="embeds"),
+    # the zoo's MoE FFN on every layer, in float32 so that no near-tie in
+    # the router's top-k goes one way in the program and the other in the
+    # reference; a capacity factor of n_experts / top_k gives every expert
+    # room for every token, so no token drops
+    "tiny-moe": dict(TINY, arch="olmoe-1b-7b", act="silu", frontend="tokens",
+                     dtype="float32", block="moe_topk",
+                     moe={"n_experts": 4, "top_k": 2, "d_ff_expert": 32,
+                          "capacity_factor": 2.0}),
 }
 TRAFFIC = {
     "tiny_serve": {"kind": "serve", "loop": "closed", "batch": 4,
@@ -33,9 +41,11 @@ TRAFFIC = {
 }
 WORKLOADS = {"tiny-tokens.serve": ("tiny-tokens", "tiny_serve"),
              "tiny-embeds.serve": ("tiny-embeds", "tiny_serve"),
+             "tiny-moe.serve": ("tiny-moe", "tiny_serve"),
              "tiny-tokens.tune": ("tiny-tokens", "tiny_tune")}
 LIMITS = {"tiny-tokens.serve": {"max_logit_gap": 0.05},
           "tiny-embeds.serve": {"max_logit_gap": 0.05},
+          "tiny-moe.serve": {"max_logit_gap": 0.05},
           "tiny-tokens.tune": {"kernel_rel_err": 0.01}}
 
 
@@ -83,3 +93,24 @@ def make_root(tmp: str, extra_metric: str = "") -> str:
 def _dump(path, obj):
     with open(path, "w") as f:
         json.dump(obj, f, indent=1)
+
+
+def break_decode(monkeypatch, fault):
+    """Plant a fault in the program's decode step: ``state`` returns the
+    cache unchanged, ``token`` alters the served token where the step
+    produces it."""
+    from repro.models import steps as S
+
+    make = S.make_decode_step
+
+    def broken(cfg, registry=None):
+        step = make(cfg, registry=registry)
+
+        def serve_step(params, batch, caches, cache_len):
+            nxt, logits, new = step(params, batch, caches, cache_len)
+            if fault == "state":
+                return nxt, logits, caches
+            return (nxt + 1) % cfg.vocab, logits, new
+        return serve_step
+
+    monkeypatch.setattr(S, "make_decode_step", broken)
