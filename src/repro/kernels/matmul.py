@@ -27,7 +27,9 @@ from repro.core.tiling import VMEM_LIMIT_BYTES, block_error
 from repro.runtime.device import resolve_interpret
 
 
-def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
+def _mm_kernel(*refs, n_k: int):
+    # a stacked call passes its scalar-prefetched layer index first
+    a_ref, b_ref, o_ref, acc_ref = refs[-4:]
     k_i = pl.program_id(2)
 
     @pl.when(k_i == 0)
@@ -47,6 +49,13 @@ def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
 
 
+def stack_divides(k: int, n: int, bk: int, bn: int) -> bool:
+    """Whether the block ``(bk, bn)`` (clamped as :func:`matmul` clamps
+    it) tiles a ``[L, k, n]`` stack with no padding: a stack is never
+    padded, since that would copy every layer of it."""
+    return k % min(bk, k) == 0 and n % min(bn, n) == 0
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("bm", "bk", "bn", "grid_order", "interpret", "out_dtype"),
@@ -55,6 +64,7 @@ def matmul(
     a: jax.Array,
     b: jax.Array,
     *,
+    layer: Optional[jax.Array] = None,
     bm: int = 128,
     bk: int = 128,
     bn: int = 128,
@@ -68,15 +78,25 @@ def matmul(
     output sliced back — the ``tail`` semantics of the loop IR.
     ``interpret=None`` compiles with Mosaic on a TPU and interprets
     elsewhere.
+
+    ``b`` may instead be a stack ``[L, k, n]`` of per-layer weights, with
+    ``layer`` a traced int32 index: C = A @ B[layer].  The index is
+    scalar-prefetched and B's index map reads that layer's blocks straight
+    out of the stack, so no copy of the layer's weight is made.  The stack
+    is never padded: its block must divide k and n (:func:`stack_divides`).
     """
     interpret = resolve_interpret(interpret)
     m, k = a.shape
-    k2, n = b.shape
-    assert k == k2, (a.shape, b.shape)
+    stacked = layer is not None
+    k2, n = b.shape[-2:]
+    assert k == k2 and b.ndim == 2 + stacked, (a.shape, b.shape)
     out_dtype = out_dtype or a.dtype
     bm, bk, bn = min(bm, m), min(bk, k), min(bn, n)
 
     pm, pk, pn = -m % bm, -k % bk, -n % bn
+    if stacked and (pk or pn):
+        raise ValueError(f"block (bk, bn)=({bk}, {bn}) does not divide the "
+                         f"stack {tuple(b.shape)}, which is never padded")
     if pm or pk:
         a = jnp.pad(a, ((0, pm), (0, pk)))
     if pk or pn:
@@ -89,29 +109,37 @@ def matmul(
         if err is not None:
             raise ValueError(err)
 
-    if grid_order == "mn":
-        grid = (gm, gn, gk)
-        a_map = lambda i, j, kk: (i, kk)
-        b_map = lambda i, j, kk: (kk, j)
-        o_map = lambda i, j, kk: (i, j)
-    else:  # "nm": n outermost
-        grid = (gn, gm, gk)
-        a_map = lambda j, i, kk: (i, kk)
-        b_map = lambda j, i, kk: (kk, j)
-        o_map = lambda j, i, kk: (i, j)
+    def at(index_map):
+        """``index_map`` of (i, j, kk, *layer ref) as the grid order
+        passes them"""
+        if grid_order == "mn":
+            return index_map
+        # "nm": n outermost
+        return lambda j, i, kk, *layer_ref: index_map(i, j, kk, *layer_ref)
 
-    out = pl.pallas_call(
+    grid = (gm, gn, gk) if grid_order == "mn" else (gn, gm, gk)
+    a_spec = pl.BlockSpec((bm, bk), at(lambda i, j, kk, *_: (i, kk)))
+    o_spec = pl.BlockSpec((bm, bn), at(lambda i, j, kk, *_: (i, j)))
+    if stacked:
+        b_spec = pl.BlockSpec((None, bk, bn),
+                              at(lambda i, j, kk, lr: (lr[0], kk, j)))
+    else:
+        b_spec = pl.BlockSpec((bk, bn), at(lambda i, j, kk: (kk, j)))
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
+    call = functools.partial(
+        pl.pallas_call,
         functools.partial(_mm_kernel, n_k=gk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), a_map),
-            pl.BlockSpec((bk, bn), b_map),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((m + pm, n + pn), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(a, b)
+    )
+    if stacked:
+        out = call(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=[a_spec, b_spec],
+            out_specs=o_spec, scratch_shapes=scratch),
+        )(jnp.reshape(layer, (1,)).astype(jnp.int32), a, b)
+    else:
+        out = call(grid=grid, in_specs=[a_spec, b_spec], out_specs=o_spec,
+                   scratch_shapes=scratch)(a, b)
     return out[:m, :n]

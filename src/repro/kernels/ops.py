@@ -35,6 +35,7 @@ from repro.runtime.device import on_tpu
 from .flash_attention import flash_attention as _flash_attention
 from .mamba_scan import mamba_scan as _mamba_scan
 from .matmul import matmul as _matmul
+from .matmul import stack_divides
 from .rwkv6_scan import rwkv6_chunk_scan as _rwkv6_chunk_scan
 
 _REGISTRY: Optional[ScheduleRegistry] = None
@@ -92,13 +93,16 @@ def serving_stats(reset: bool = False) -> Dict[str, Any]:
     ``hits``  — workload found in the registry;
     ``misses`` — matmul-shaped contraction with no entry (cold miss);
     ``routed`` — hits actually lowered through the Pallas tiled kernel
-    (subset of hits: CPU hosts count the hit but keep the XLA lowering).
+    (subset of hits: CPU hosts count the hit but keep the XLA lowering);
+    ``stacked`` — routed calls whose kernel read its layer's weight
+    straight out of the stacked parameters (subset of routed).
     """
     per_key = {k: dict(v) for k, v in _SERVING_STATS.items()}
     out = {
         "hits": sum(v.get("hits", 0) for v in per_key.values()),
         "misses": sum(v.get("misses", 0) for v in per_key.values()),
         "routed": sum(v.get("routed", 0) for v in per_key.values()),
+        "stacked": sum(v.get("stacked", 0) for v in per_key.values()),
         "per_key": per_key,
     }
     if reset:
@@ -112,7 +116,7 @@ def reset_serving_stats() -> None:
 
 def _count(key: str, field: str) -> None:
     slot = _SERVING_STATS.setdefault(key, {"hits": 0, "misses": 0,
-                                           "routed": 0})
+                                           "routed": 0, "stacked": 0})
     slot[field] += 1
 
 
@@ -191,6 +195,7 @@ def _route_pallas(pallas: str) -> Tuple[bool, bool]:
 
 
 def tuned_einsum(spec: str, a: jax.Array, b: jax.Array, *,
+                 layer: Optional[jax.Array] = None,
                  registry: Optional[ScheduleRegistry] = None,
                  pallas: str = "auto",
                  preferred_element_type=None) -> jax.Array:
@@ -202,16 +207,27 @@ def tuned_einsum(spec: str, a: jax.Array, b: jax.Array, *,
     kernel with the tuned BlockSpec; cold misses, non-matmul shapes, and
     hosts where Mosaic can't compile fall back to ``jnp.einsum`` — always
     numerically interchangeable with the fallback.
+
+    With ``layer`` (a traced int32), ``b`` is a stack of per-layer weights
+    and the contraction takes ``b[layer]``.  A routed ``...k,kn`` hit
+    whose block divides the weight gives the kernel the whole stack and
+    the index (counted ``stacked``), so the layer's weight is not copied
+    out first; every other path slices the layer and goes on as above.
     """
     reg = registry if registry is not None else _SERVING
 
+    def weight():
+        return b if layer is None else \
+            jax.lax.dynamic_index_in_dim(b, layer, keepdims=False)
+
     def _fallback():
-        return jnp.einsum(spec, a, b,
+        return jnp.einsum(spec, a, weight(),
                           preferred_element_type=preferred_element_type)
 
     if reg is None:
         return _fallback()
-    parsed = _parse_matmul_spec(spec, a.shape, b.shape)
+    parsed = _parse_matmul_spec(spec, a.shape,
+                                b.shape if layer is None else b.shape[1:])
     if parsed is None:
         return _fallback()
     m, k, n, transpose_rhs = parsed
@@ -232,11 +248,17 @@ def tuned_einsum(spec: str, a: jax.Array, b: jax.Array, *,
     go = [it for it in entry.get("grid_order", []) if it in ("m", "n")]
     order = "nm" if go and go[0] == "n" else "mn"
     a2 = a.reshape(m, k)
-    b2 = b.T if transpose_rhs else b
     out_dtype = preferred_element_type if preferred_element_type is not None \
         else a.dtype
-    out = _matmul(a2, b2, bm=block["m"], bk=block["k"], bn=block["n"],
-                  grid_order=order, interpret=interpret, out_dtype=out_dtype)
+    kw = dict(bm=block["m"], bk=block["k"], bn=block["n"], grid_order=order,
+              interpret=interpret, out_dtype=out_dtype)
+    if (layer is not None and not transpose_rhs
+            and stack_divides(k, n, block["k"], block["n"])):
+        _count(wl_key, "stacked")
+        out = _matmul(a2, b, layer=layer, **kw)
+    else:
+        b2 = weight()
+        out = _matmul(a2, b2.T if transpose_rhs else b2, **kw)
     return out.reshape(*a.shape[:-1], n)
 
 

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,19 +45,39 @@ def _serving_ops():
     return _kops if _kops.serving_registry() is not None else None
 
 
-def dense(x: jax.Array, w: jax.Array) -> jax.Array:
+class LayerWeight(NamedTuple):
+    """Layer ``index`` (a traced int32) of a stacked ``[n_layers, K, N]``
+    weight, kept as the whole stack and the index, so that the routed
+    kernel reads the layer's blocks out of the stack with no copy."""
+    stack: jax.Array
+    index: jax.Array
+
+
+#: the weights :func:`dense` consumes, by the parameter group holding
+#: them: while a registry is served the layer scan passes them as
+#: :class:`LayerWeight` views (``transformer._run_layers``)
+DENSE_WEIGHTS = {"attn": ("wq", "wk", "wv", "wo"),
+                 "cross": ("wq", "wk", "wv", "wo"),
+                 "mlp": ("w_gate", "w_up", "w_down")}
+
+
+def dense(x: jax.Array, w) -> jax.Array:
     """``x (..., K) @ w (K, N)`` — the model zoo's matmul hot path.
 
     With a tuned-schedule registry being served (``kernels.ops.serving``),
     the contraction routes through :func:`repro.kernels.ops.tuned_einsum`
-    (registry lookup + Pallas tiled kernel on hit); otherwise it is exactly
-    the plain ``@`` it always was.
+    (registry lookup + Pallas tiled kernel on hit), and ``w`` may be a
+    :class:`LayerWeight`; otherwise it is exactly the plain ``@`` it
+    always was.
     """
     kops = _serving_ops()
     if kops is None:
         return x @ w
     free = "abce"[: x.ndim - 1]  # skip k/n (bound in the spec)
-    return kops.tuned_einsum(f"{free}k,kn->{free}n", x, w)
+    spec = f"{free}k,kn->{free}n"
+    if isinstance(w, LayerWeight):
+        return kops.tuned_einsum(spec, x, w.stack, layer=w.index)
+    return kops.tuned_einsum(spec, x, w)
 
 
 # ---------------------------------------------------------------------------

@@ -33,7 +33,7 @@ from repro.configs.base import (
     LayerSpec,
     ModelConfig,
 )
-from repro.runtime.sharding import ashard, keep_kv_layout
+from repro.runtime.sharding import ashard, current_mesh, keep_kv_layout
 from . import layers as L
 from . import mamba as M
 from . import moe as X
@@ -356,21 +356,54 @@ def _run_layers(params, cfg, x, caches, cache_len, positions, encoder,
         # (decisive for wide heterogeneous periods, EXPERIMENTS §Perf).
         block_fns = [jax.checkpoint(f) for f in block_fns]
 
+    scanned, held = params["blocks"], ({},) * n_specs
+    # under a mesh the kernel's custom call would gather a sharded stack
+    # whole, where a scanned slice gathers one layer
+    if L._serving_ops() is not None and current_mesh() is None:
+        scanned, held = zip(*map(_hold_dense_weights, scanned))
+
     def period_body(carry, blocks):
         x, aux, li, pcaches = carry
         pcaches = list(pcaches)
         for pos in range(n_specs):
             x, pcaches[pos], aux = block_fns[pos](
-                blocks[pos], x, pcaches[pos], li, aux, cache_len, positions,
-                encoder)
+                _layer_views(blocks[pos], held[pos], li), x, pcaches[pos],
+                li, aux, cache_len, positions, encoder)
         return (x, aux, li + 1, tuple(pcaches)), None
 
     body = jax.checkpoint(period_body) if policy == "period" else period_body
     carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
              caches if caches is not None else (None,) * n_specs)
     (x, aux, _, new_caches), _ = jax.lax.scan(
-        body, carry, params["blocks"], length=cfg.n_periods)
+        body, carry, tuple(scanned), length=cfg.n_periods)
     return x, (new_caches if caches is not None else None), aux
+
+
+def _hold_dense_weights(p):
+    """(``p`` less the stacks that ``L.dense`` consumes, those stacks).
+
+    While a registry is served the layer scan holds these stacks whole
+    instead of scanning them, and hands each layer a
+    :class:`~repro.models.layers.LayerWeight` view: the routed kernel then
+    reads the layer's blocks out of the stack, where a scanned weight is a
+    slice that XLA copies before the kernel can read it."""
+    p, held = dict(p), {}
+    for group, names in L.DENSE_WEIGHTS.items():
+        if group in p:
+            p[group] = dict(p[group])
+            held[group] = {n: p[group].pop(n) for n in names}
+    return p, held
+
+
+def _layer_views(p, held, li):
+    """``p`` with layer ``li``'s views of the ``held`` stacks put back."""
+    if not held:
+        return p
+    p = dict(p)
+    for group, stacks in held.items():
+        p[group] = dict(p[group], **{n: L.LayerWeight(w, li)
+                                     for n, w in stacks.items()})
+    return p
 
 
 def hidden_states(

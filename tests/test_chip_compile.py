@@ -9,6 +9,7 @@ runs this file loads the TPU library.  The CPU tests cover the legality
 check, the compile-cache placement and the harvest's platform independence.
 """
 import dataclasses
+import json
 import os
 import re
 import subprocess
@@ -125,6 +126,73 @@ def test_routed_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     stats = ops.serving_stats(reset=True)
     assert stats["misses"] == 0 and stats["routed"] == stats["hits"] > 0
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: musicgen-large's served decode blocks at batch 16 (the analytical
+#: tune's table): (bm, bk, bn), m outermost
+MUSICGEN_DECODE_BLOCKS = {(16, 2048, 2048): (16, 2048, 2048),
+                          (16, 2048, 8192): (8, 128, 8192),
+                          (16, 8192, 2048): (8, 128, 2048)}
+
+
+#: as the benchmark's mm_roofline reads a v5e trace: the routed kernel's
+#: custom call, and a layer's [K, N] weight sliced out of its stack
+KERNEL_EVENT = r'^%matmul(\.\d+)? = .*custom_call_target="tpu_custom_call"'
+STAGING_EVENT = re.compile(
+    r"^%[\w.-]*dynamic-slice[\w.-]* = (\w+)\[(\d+),(\d+)\]")
+
+
+def test_routed_decode_step_reads_weights_from_the_stack_on_v5e(
+        topo, one_chip, tmp_path, monkeypatch):
+    """musicgen-large's decode step at its served blocks, compiled for
+    v5e: each routed kernel reads its layer's weight out of the
+    [n_layers, K, N] stack, so no dynamic-slice fusion copies a layer's
+    [K, N] weight out first (mm_roofline's staging events), and the
+    kernel keeps the HLO name ``%matmul.N`` its roofline finds it by."""
+    from repro.configs import ShapeCell, get_config, input_specs
+    from repro.core.registry import ScheduleRegistry
+    from repro.kernels import ops
+    from repro.models import steps as S
+    from repro.models import transformer as T
+
+    cfg = get_config("musicgen-large")
+    path = tmp_path / "served.json"
+    path.write_text(json.dumps({
+        f"mm:{m}x{k}x{n}:{cfg.dtype}": {
+            "gflops": 1.0, "actions": [], "grid_order": ["m", "k", "n"],
+            "block": {"m": bm, "k": bk, "n": bn}}
+        for (m, k, n), (bm, bk, bn) in MUSICGEN_DECODE_BLOCKS.items()}))
+    monkeypatch.setattr(ops, "_route_pallas", lambda pallas: (True, False))
+
+    def place(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
+    specs = input_specs(cfg, ShapeCell("serve", 512, 16, "decode"))
+    ops.reset_serving_stats()
+    step = S.make_decode_step(cfg, registry=ScheduleRegistry(str(path)))
+    with jax.default_device(topo.devices[0]):
+        text = jax.jit(step, donate_argnums=(2,)).lower(
+            params, place(specs["batch"]), place(specs["caches"]),
+            place(specs["cache_len"])).compile().as_text()
+    stats = ops.serving_stats(reset=True)
+    assert stats["misses"] == 0
+    # the LM head (transposed weight) shares 16x2048x2048 with q/k/v/o
+    # and is the one routed call the stack does not serve
+    assert stats["stacked"] == stats["routed"] - 1 > 0
+    weights = {(k, n) for _, k, n in MUSICGEN_DECODE_BLOCKS}
+    staged = []
+    for ln in text.splitlines():
+        m = STAGING_EVENT.match(ln.strip())
+        if m and m.group(1) == "bf16" and \
+                (int(m.group(2)), int(m.group(3))) in weights:
+            staged.append(ln.strip())
+    assert staged == []
+    kernels = [ln for ln in text.splitlines()
+               if re.match(KERNEL_EVENT, ln.strip())]
+    assert len(kernels) >= len(MUSICGEN_DECODE_BLOCKS)
 
 
 def _cache_copies(text, *dims):

@@ -56,6 +56,29 @@ def test_matmul_dtypes(dtype):
                                rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("m", [16, 20])           # 20: A padded along m
+@pytest.mark.parametrize("order", ["mn", "nm"])
+@pytest.mark.parametrize("li", [0, 1, 2])
+def test_stacked_matmul_reads_the_layer_bitwise(li, order, m):
+    """B a stack [L, K, N] and a traced layer index: the kernel reads the
+    layer's blocks out of the stack and gives exactly matmul(a, stack[li])."""
+    stack = _rand(jax.random.PRNGKey(7), (3, 256, 384), jnp.bfloat16)
+    a = _rand(jax.random.PRNGKey(8), (m, 256), jnp.bfloat16)
+    kw = dict(bm=8, bk=128, bn=128, grid_order=order)
+    out = jax.jit(lambda a, s, i: matmul(a, s, layer=i, **kw))(
+        a, stack, jnp.int32(li))
+    np.testing.assert_array_equal(out, matmul(a, stack[li], **kw))
+
+
+@pytest.mark.parametrize("block", [(8, 96, 128), (8, 128, 160)])
+def test_stacked_matmul_refuses_a_block_that_pads_the_stack(block):
+    bm, bk, bn = block
+    stack = jnp.zeros((2, 256, 384))
+    with pytest.raises(ValueError, match="never padded"):
+        matmul(jnp.zeros((8, 256)), stack, layer=jnp.int32(0), bm=bm, bk=bk,
+               bn=bn)
+
+
 def test_matmul_registry_integration(tmp_path):
     from repro.core import LoopTuner
 

@@ -278,6 +278,94 @@ def test_serving_context_activates_dense(tmp_path):
     assert stats["hits"] == 1
 
 
+def _blocks_registry(path, blocks, order="mn"):
+    """A table giving each ``mm`` key of ``blocks`` its (bm, bk, bn)."""
+    grid = ["m", "n", "k"] if order == "mn" else ["n", "m", "k"]
+    path.write_text(json.dumps({
+        key: {"gflops": 1.0, "actions": [], "grid_order": grid,
+              "block": dict(zip("mkn", block))}
+        for key, block in blocks.items()}))
+    return ScheduleRegistry(str(path))
+
+
+@pytest.mark.parametrize("bk,bn,stacked", [
+    (32, 32, True),      # divides K = 64 and N = 96
+    (48, 32, False),     # does not divide K: the stack would be padded
+    (32, 40, False),     # nor N
+])
+def test_tuned_einsum_stacked_weight_takes_the_stack_only_when_it_divides(
+        tmp_path, bk, bn, stacked):
+    """A weight given as (stack, layer index): a block that divides the
+    layer's K and N reads the stack in the kernel (counted ``stacked``);
+    any other slices the layer first, as a scanned weight is."""
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 8, 64))
+    stack = jax.random.normal(jax.random.PRNGKey(7), (3, 64, 96))
+    reg = _blocks_registry(tmp_path / "reg.json",
+                           {"mm:16x64x96:float32": (8, bk, bn)})
+    K.reset_serving_stats()
+    for li in range(3):
+        out = jax.jit(lambda x, s, i: K.tuned_einsum(
+            "abk,kn->abn", x, s, layer=i, registry=reg,
+            pallas="interpret"))(x, stack, jnp.int32(li))
+        ref = K.tuned_einsum("abk,kn->abn", x, stack[li], registry=reg,
+                             pallas="interpret")
+        np.testing.assert_array_equal(out, ref)
+    stats = K.serving_stats(reset=True)
+    slot = stats["per_key"]["mm:16x64x96:float32"]
+    assert slot["routed"] == 6
+    assert slot["stacked"] == (3 if stacked else 0) == stats["stacked"]
+
+
+def _serve_tiny(params, cfg, reg, tokens):
+    """(prefill's last logits, one decode step's logits) through the
+    served steps, with serving_stats of their traces."""
+    from repro.models import steps as S
+
+    K.reset_serving_stats()
+    last, caches, clen = jax.jit(S.make_prefill_step(
+        cfg, max_len=16, registry=reg))(params, {"tokens": tokens})
+    nxt = jnp.argmax(last, -1).astype(jnp.int32)
+    _, logits, _ = jax.jit(S.make_decode_step(cfg, registry=reg))(
+        params, {"tokens": nxt[:, None]}, caches, clen)
+    return last, logits, K.serving_stats(reset=True)
+
+
+@pytest.mark.parametrize("order", ["mn", "nm"])
+def test_served_steps_read_stacked_weights_bitwise(tmp_path, monkeypatch,
+                                                   order):
+    """A tiny dense model, every dense site routed through the interpreted
+    kernel: the prefill and decode logits with each layer's weights read
+    out of the stacks equal those of the scan that slices them, and every
+    in-scan key is served by the stack (the LM head is not)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import transformer as T
+
+    cfg = dataclasses.replace(get_config("phi3-mini-3.8b").smoke(),
+                              n_layers=3)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, cfg.vocab)
+    # the keys the steps look up, from a trace with an empty table
+    *_, cold = _serve_tiny(params, cfg, ScheduleRegistry(), tokens)
+    reg = _blocks_registry(tmp_path / "reg.json",
+                           {key: (8, 32, 32) for key in cold["per_key"]},
+                           order)
+    monkeypatch.setattr(K, "_route_pallas", lambda pallas: (True, True))
+    last, logits, stats = _serve_tiny(params, cfg, reg, tokens)
+    with monkeypatch.context() as mp:
+        mp.setattr(T, "_hold_dense_weights", lambda p: (p, {}))
+        ref_last, ref_logits, ref = _serve_tiny(params, cfg, reg, tokens)
+    np.testing.assert_array_equal(last, ref_last)
+    np.testing.assert_array_equal(logits, ref_logits)
+    assert ref["stacked"] == 0 and ref["routed"] == stats["routed"] > 0
+    assert stats["misses"] == 0
+    for key, slot in stats["per_key"].items():
+        head = key.split(":")[1].endswith(f"x{cfg.vocab}")
+        assert slot["routed"] > 0
+        assert slot["stacked"] == (0 if head else slot["routed"]), key
+
+
 # ---------------------------------------------------------------------------
 # Harvest → tune: the offline pre-pass
 # ---------------------------------------------------------------------------
